@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import LoopSpec, TensorMap, autotune, perf_model
+from repro.launch.compile_cache import enable_compile_cache
 
 JSON_PATH = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -186,6 +187,7 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--smoke", action="store_true",
                    help="reduced sizes, equality checks only, no JSON")

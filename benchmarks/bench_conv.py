@@ -11,6 +11,7 @@ import numpy as np
 
 from repro.kernels import ref
 from repro.kernels.conv import block_conv_tensors, conv2d_parlooper
+from repro.launch.compile_cache import enable_compile_cache
 
 # (H, W, C, K, R, S, stride) — representative RN50 layers, N scaled to 2
 LAYERS = [
@@ -49,5 +50,6 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     for r in run():
         print(",".join(map(str, r)))

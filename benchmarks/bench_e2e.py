@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve import ServeConfig, generate
 from repro.train import TrainConfig, init_train_state, make_train_step
@@ -97,5 +98,6 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     for r in run():
         print(",".join(map(str, r)))

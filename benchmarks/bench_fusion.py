@@ -41,6 +41,7 @@ from repro.core import perf_model
 from repro.fusion import rng as frng
 from repro.fusion.library import OUTPUT_DROPOUT_SALT
 from repro.kernels.brgemm import pick_tiles
+from repro.launch.compile_cache import enable_compile_cache
 
 DROPOUT_JSON_PATH = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -535,6 +536,7 @@ def _gated_mlp_rows(rng, smoke):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small shapes + interpret-mode parity check")

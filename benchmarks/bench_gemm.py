@@ -15,6 +15,7 @@ from repro.core import LoopSpec, TensorMap, ThreadedLoop, autotune, perf_model
 from repro.core.loops import LegalityError
 from repro.core.pallas_lowering import validate_reduction_innermost
 from repro.kernels.brgemm import pick_tiles
+from repro.launch.compile_cache import enable_compile_cache
 
 # paper Fig. 2 (square / skewed) + Fig. 5 (BERT/GPT/DLRM shapes)
 SHAPES = [
@@ -68,5 +69,6 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     for r in run():
         print(",".join(map(str, r)))

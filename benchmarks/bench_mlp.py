@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import tpp
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _fused(x, w, b):
@@ -56,5 +57,6 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     for r in run():
         print(",".join(map(str, r)))

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.core import (LoopSpec, TensorMap, ThreadedLoop, autotune,
                         perf_model, tpp)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _measure_spec(spec, loops, A, B, k_step, bm, bk, bn, nb, mb):
@@ -133,5 +134,6 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     for r in run():
         print(",".join(map(str, r)))

@@ -37,6 +37,8 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 JSON_PATH = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "BENCH_serve.json")
@@ -344,6 +346,7 @@ def run_faults(smoke: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="smaller trace + relaxed throughput gate")

@@ -14,6 +14,7 @@ import numpy as np
 from repro.core import perf_model
 from repro.kernels import ref
 from repro.kernels.block_spmm import densify_to_bcsr
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run():
@@ -61,5 +62,6 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     for r in run():
         print(",".join(map(str, r)))

@@ -7,6 +7,8 @@ PR; such modules advertise the path via a ``JSON_PATH`` attribute."""
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 MODULES = [
     "bench_gemm",        # Figs. 2/4/5
     "bench_mlp",         # Fig. 3
@@ -37,4 +39,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

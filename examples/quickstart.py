@@ -18,6 +18,9 @@ import numpy as np
 from repro.core import (LoopSpec, TensorMap, ThreadedLoop, autotune,
                         plan_pallas, tpp)
 from repro.kernels.brgemm import matmul_pallas
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # --- problem: C[M,N] = A[M,K] @ B[K,N], blocked by (bm, bk, bn) -----------
 M, K, N = 256, 512, 256

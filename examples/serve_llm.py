@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve import ServeConfig
 from repro.serve.decode import make_serve_step
@@ -61,4 +62,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.kernels import ref
 from repro.kernels.block_spmm import densify_to_bcsr
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def block_prune(w, sparsity, bs=8):
@@ -81,4 +82,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

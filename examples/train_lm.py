@@ -16,6 +16,7 @@ import dataclasses
 
 from repro.configs import get_config
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import TrainConfig, TrainerConfig, train
 
 
@@ -59,4 +60,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

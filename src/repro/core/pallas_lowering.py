@@ -37,16 +37,8 @@ from repro.core.loops import Level, LoopNest
 
 __all__ = [
     "TensorMap", "PallasPlan", "plan_pallas", "make_pallas_fn",
-    "validate_reduction_innermost", "tpu_compiler_params",
+    "validate_reduction_innermost",
 ]
-
-# jax renamed TPUCompilerParams → CompilerParams across 0.4.x/0.5.x; accept both
-_COMPILER_PARAMS_CLS = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None))
-
-
-def tpu_compiler_params(**kw):
-    return _COMPILER_PARAMS_CLS(**kw)
 
 
 def validate_reduction_innermost(nest: LoopNest, out_letters, reduction_letters):
@@ -231,7 +223,7 @@ def make_pallas_fn(
         ind = plan.logical_index_fn()
         body(ind, *refs)
 
-    compiler_params = tpu_compiler_params(
+    compiler_params = pltpu.CompilerParams(
         dimension_semantics=plan.dimension_semantics,
         vmem_limit_bytes=vmem_limit_bytes,
     )
@@ -263,18 +255,16 @@ def make_pallas_fn(
                 f"{lvl.mesh_axis!r} has size {actual}"
             )
 
-    from jax.experimental.shard_map import shard_map
-
     def sharded(*operands):
         out = call(*operands)
         for axis in plan.sharded_reduction_axes:
             out = jax.lax.psum(out, axis)
         return out
 
-    return shard_map(
+    return jax.shard_map(
         sharded,
         mesh=mesh,
         in_specs=tuple(plan.in_pspecs),
         out_specs=plan.out_pspec,
-        check_rep=False,
+        check_vma=False,
     )

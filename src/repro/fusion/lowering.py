@@ -802,10 +802,10 @@ class ForcedPallasFailure(RuntimeError):
 
 
 def _fallback_enabled() -> bool:
-    # strict mode (REPRO_FUSION_FALLBACK=0): lowering failures are fatal,
-    # as before this layer existed — for CI jobs that must not silently
-    # lose fused coverage
-    return os.environ.get("REPRO_FUSION_FALLBACK", "1") != "0"
+    # opt-in (REPRO_FUSION_FALLBACK=1): by default a lowering failure is
+    # fatal, so a run that asked for fused kernels never reports success
+    # on the reference path without them
+    return os.environ.get("REPRO_FUSION_FALLBACK", "0") == "1"
 
 
 def fallback_blocklist() -> dict[str, str]:
@@ -845,16 +845,18 @@ def _note_fallback(graph: TppGraph, exc: BaseException) -> None:
                                      graph=graph.name, reason=reason)
         _LOG.warning(
             "fused Pallas lowering of graph %r failed (%s); falling back to "
-            "the composed-TPP XLA reference for this graph (set "
-            "REPRO_FUSION_FALLBACK=0 to make this fatal)", graph.name, reason)
+            "the composed-TPP XLA reference for this graph "
+            "(REPRO_FUSION_FALLBACK=1)", graph.name, reason)
 
 
 def _guarded_pallas(graph: TppGraph, backend: str, kw: dict):
-    """Compile the fused Pallas path with call-time XLA fallback.  Pallas
-    plan/lowering errors surface either at compile() time (epilogue-band
-    legality) or at first call per shape (tile divisibility, Mosaic) — both
-    are caught, logged once, blocklisted, and rerouted to the XLA
-    reference; ``TypeError`` (caller passed wrong operands) stays fatal."""
+    """Compile the fused Pallas path with an opt-in call-time XLA fallback
+    (``REPRO_FUSION_FALLBACK=1``).  Pallas plan/lowering errors surface
+    either at compile() time (epilogue-band legality) or at first call per
+    shape (tile divisibility, Mosaic); with the fallback on, both are
+    caught, logged once, blocklisted, and rerouted to the XLA reference.
+    Without it they propagate.  ``TypeError`` (caller passed wrong
+    operands) is always fatal."""
     xla_kw = {k: v for k, v in kw.items() if k == "out_dtype"}
     state: dict = {"xla_fn": None}
 
@@ -901,12 +903,13 @@ def compile_for_backend(graph: TppGraph, backend: Optional[str] = None, **kw):
     call is pure waste.  The returned callable itself caches one pallas plan
     per distinct operand-shape/dtype tuple.
 
-    Unlike :func:`compile` (which raises on lowering failures — the strict
-    path tests and tools use), the pallas-backend callables returned here
-    degrade gracefully: a graph whose fused lowering fails is logged once,
+    Lowering failures raise, as in :func:`compile`.  With
+    ``REPRO_FUSION_FALLBACK=1`` the pallas-backend callables returned here
+    degrade instead: a graph whose fused lowering fails is logged once,
     blocklisted, and routed through the composed-TPP XLA reference, so
     ``use_fusion=True`` models survive a backend that cannot compile a
-    shape.  ``REPRO_FUSION_FALLBACK=0`` restores strictness."""
+    shape (``fallback_blocklist()`` and the ``fusion.fallbacks`` counter
+    say which)."""
     from repro.kernels import ops
     backend = backend or ops.current_backend()
     if backend == "xla":

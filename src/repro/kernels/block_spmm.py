@@ -32,7 +32,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.pallas_lowering import tpu_compiler_params
 
 __all__ = ["block_spmm_pallas", "grouped_matmul_pallas", "densify_to_bcsr"]
 
@@ -127,7 +126,7 @@ def block_spmm_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nrows_b * bm, n), out_dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )
@@ -176,7 +175,7 @@ def grouped_matmul_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_rows, f), out_dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
     )

@@ -25,7 +25,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.pallas_lowering import tpu_compiler_params
 
 __all__ = ["flash_attention_pallas", "flash_decode_pallas"]
 
@@ -159,7 +158,7 @@ def _legacy_flash_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )
@@ -234,7 +233,7 @@ def flash_decode_pallas(
         @pl.when(j == nkv - 1)
         def _():
             l = jnp.maximum(l_ref[:1, :1], 1e-30)
-            o_ref[0, 0] = (acc_ref[...] / l)[0].astype(o_ref.dtype)
+            o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
     grid = (b, h, nkv)
     fn = pl.pallas_call(
@@ -246,16 +245,19 @@ def flash_decode_pallas(
             pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, j: (b_, h_ // g, j, 0)),
             pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, j: (b_, h_ // g, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda b_, h_, j: (b_, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, d), out_dtype),
+        # the output carries the same singleton query axis as ``q``, so the
+        # block's last two dims equal the array's (Mosaic's tiling rule)
+        out_specs=pl.BlockSpec((1, 1, 1, d), lambda b_, h_, j: (b_, h_, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), out_dtype),
         scratch_shapes=[
             pltpu.VMEM((1, d), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )
-    return fn(length.astype(jnp.int32), q[:, :, None, :], k_cache, v_cache)
+    out = fn(length.astype(jnp.int32), q[:, :, None, :], k_cache, v_cache)
+    return out[:, :, 0, :]

@@ -23,7 +23,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.pallas_lowering import tpu_compiler_params
 
 __all__ = ["fused_output_pallas", "fused_output_ref"]
 
@@ -125,7 +124,7 @@ def fused_output_pallas(x, w, bias, residual, gamma, beta, *, keep_mask=None,
             pltpu.VMEM((bm, bn), jnp.float32),   # K accumulator
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
     )

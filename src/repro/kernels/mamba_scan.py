@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.pallas_lowering import tpu_compiler_params
 
 __all__ = ["mamba_scan_pallas"]
 
@@ -101,7 +100,7 @@ def mamba_scan_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((dch, n), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )

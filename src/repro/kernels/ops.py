@@ -103,7 +103,7 @@ def decode_attention(q, k_cache, v_cache, *, length=None, window=None,
 def paged_decode_attention(q, k_pool, v_pool, page_table, *, page_size,
                            length, window=None, out_dtype=None, backend=None,
                            block_kv=128):
-    """Decode attention over token-major page pools (P, page_size, Hk, D)
+    """Decode attention over token-major page pools (P, page_size, Hk·D)
     indexed by ``page_table`` (B, maxp) — the serving engine's KV layout.
 
     The XLA path runs the gather in pool layout (no transpose copy); Pallas
@@ -115,14 +115,12 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, *, page_size,
         return _ref.paged_decode_attention_ref(
             q, k_pool, v_pool, page_table, page_size=page_size,
             length=length, window=window, out_dtype=out_dtype)
-    import jax.numpy as jnp
     from repro.kernels.flash_attention import flash_decode_pallas
     b, maxp = page_table.shape
-    # (B, maxp, ps, Hk, D) → (B, Hk, maxp·ps, D)
-    k = jnp.swapaxes(k_pool[page_table].reshape(
-        b, maxp * page_size, k_pool.shape[2], k_pool.shape[3]), 1, 2)
-    v = jnp.swapaxes(v_pool[page_table].reshape(
-        b, maxp * page_size, v_pool.shape[2], v_pool.shape[3]), 1, 2)
+    s, hk = maxp * page_size, k_pool.shape[2] // q.shape[-1]
+    # (B, maxp, ps, Hk·D) → (B, Hk, maxp·ps, D)
+    k = jnp.swapaxes(k_pool[page_table].reshape(b, s, hk, -1), 1, 2)
+    v = jnp.swapaxes(v_pool[page_table].reshape(b, s, hk, -1), 1, 2)
     return flash_decode_pallas(
         q, k, v, length=length, window=window, block_kv=block_kv,
         out_dtype=out_dtype, interpret=_interp(backend),

@@ -159,7 +159,7 @@ def decode_attention_ref(q, k_cache, v_cache, *, length=None, window=None,
 def paged_decode_attention_ref(q, k_pool, v_pool, page_table, *, page_size,
                                length, window=None, out_dtype=None):
     """Single-token decode over a paged KV pool: q (B, H, D); pools are
-    token-major page pools (P, page_size, Hk, D) shared by every slot;
+    token-major page pools (P, page_size, Hk·D) shared by every slot;
     ``page_table`` (B, maxp) int32 names each slot's pages (trash-page
     sentinel in unused entries); ``length`` (B,) valid prefix lengths.
 
@@ -171,11 +171,9 @@ def paged_decode_attention_ref(q, k_pool, v_pool, page_table, *, page_size,
     whole attention cost).  Positions beyond ``length`` read reserved /
     trash pages and are masked by :func:`decode_attention_ref`."""
     b, maxp = page_table.shape
-    s = maxp * page_size
-    k = jnp.swapaxes(k_pool[page_table].reshape(b, s, k_pool.shape[2], -1),
-                     1, 2)
-    v = jnp.swapaxes(v_pool[page_table].reshape(b, s, v_pool.shape[2], -1),
-                     1, 2)
+    s, hk = maxp * page_size, k_pool.shape[2] // q.shape[-1]
+    k = jnp.swapaxes(k_pool[page_table].reshape(b, s, hk, -1), 1, 2)
+    v = jnp.swapaxes(v_pool[page_table].reshape(b, s, hk, -1), 1, 2)
     return decode_attention_ref(q, k, v, length=length, window=window,
                                 out_dtype=out_dtype)
 

@@ -9,18 +9,9 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_local_mesh", "make_mesh_compat"]
+__all__ = ["make_production_mesh", "make_local_mesh"]
 
-
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer jax; Auto is the default
-    there and the only behavior on older jax, so omit it when absent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,9 +20,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     DCN/cross-pod data-parallel dimension."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many local devices exist (tests)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(_AUTO, _AUTO))

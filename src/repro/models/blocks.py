@@ -121,7 +121,7 @@ def attention_apply(cfg: ModelConfig, p, x, *, kind: str = "attn",
     sits at its own sequence length; attention masks by ``pos + 1``).
 
     Paged mode (``page_table`` (B, maxp) int32 + static ``page_size``): the
-    cache arrays are token-major page *pools* (P, page_size, Hk, hd) shared
+    cache arrays are token-major page *pools* (P, page_size, Hk·hd) shared
     by all slots;
     a slot's logical sequence lives in the pages its table row names.  Decode
     scatters the new K/V into (page, offset) and attends over the gathered
@@ -180,8 +180,8 @@ def attention_apply(cfg: ModelConfig, p, x, *, kind: str = "attn",
             assert pos.ndim == 1, "paged decode takes per-slot (B,) positions"
             pg = _page_lookup(page_table, (pos // page_size)[:, None])[:, 0]
             off = jnp.mod(pos, page_size)
-            k_pool = cache["k"].at[pg, off].set(k[:, :, 0])
-            v_pool = cache["v"].at[pg, off].set(v[:, :, 0])
+            k_pool = cache["k"].at[pg, off].set(k[:, :, 0].reshape(b, hk * hd))
+            v_pool = cache["v"].at[pg, off].set(v[:, :, 0].reshape(b, hk * hd))
             new_cache = {"k": k_pool, "v": v_pool}
             o = ops.paged_decode_attention(
                 q[:, :, 0], k_pool, v_pool, page_table,
@@ -197,8 +197,8 @@ def attention_apply(cfg: ModelConfig, p, x, *, kind: str = "attn",
             pg = _page_lookup(page_table,
                               jnp.broadcast_to(tpos // page_size, (b, s)))
             off = jnp.broadcast_to(jnp.mod(tpos, page_size), (b, s))
-            k_pool = cache["k"].at[pg, off].set(xk)   # (B,S,Hk,hd) token-major
-            v_pool = cache["v"].at[pg, off].set(xv)
+            k_pool = cache["k"].at[pg, off].set(xk.reshape(b, s, hk * hd))
+            v_pool = cache["v"].at[pg, off].set(xv.reshape(b, s, hk * hd))
             new_cache = {"k": k_pool, "v": v_pool}
             o = ops.attention(q, k, v, causal=causal, window=window)
     elif cache is not None and kind != "cross":

@@ -156,7 +156,6 @@ def _moe_maybe_sharded(cfg: ModelConfig, p, x2d, ep_axis):
     rules = active_rules()
     if ep_axis is None or rules is None or ep_axis not in rules.mesh.shape:
         return B.moe_apply(cfg, p, x2d, ep_axis=None)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = rules.mesh
@@ -179,12 +178,12 @@ def _moe_maybe_sharded(cfg: ModelConfig, p, x2d, ep_axis):
     if "shared" in p:
         wspec["shared"] = jax.tree.map(lambda _: P(), p["shared"])
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(B.moe_apply, cfg, ep_axis=ep_axis),
         mesh=mesh,
         in_specs=(wspec, tok_spec),
         out_specs=(tok_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(p, x2d)
 
@@ -529,11 +528,13 @@ def init_paged_cache(cfg: ModelConfig, num_slots: int, num_pages: int,
             c["mla"] = {"latent": jnp.zeros(
                 (rows, page_size, cfg.kv_lora_rank + cfg.rope_head_dim), dt)}
         else:
-            # token-major (rows, page_size, hk, hd): gathers land directly in
-            # the paged_decode_attention einsum layout (no transpose copy)
+            # token-major (rows, page_size, hk·hd), heads folded into the
+            # last axis: the TPU tiles an array's last two dims by
+            # (8·k, 128), so (page_size, hk·hd) lies unpadded where
+            # (hk, hd) = (36, 64) (minicpm-2b) pads each pool 2.2×
             c["attn"] = {
-                "k": jnp.zeros((rows, page_size, hk, hd), dt),
-                "v": jnp.zeros((rows, page_size, hk, hd), dt),
+                "k": jnp.zeros((rows, page_size, hk * hd), dt),
+                "v": jnp.zeros((rows, page_size, hk * hd), dt),
             }
         return c
 

@@ -44,7 +44,6 @@ import dataclasses
 import functools
 import math
 import time
-from functools import partial
 from typing import NamedTuple, Optional
 
 import jax
@@ -53,6 +52,7 @@ import numpy as np
 
 import repro.obs as obs
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from repro.models import lm
 from repro.obs.recorder import FlightRecorder
 from repro.serve.faults import NO_FAULTS, POISON_OFF, FaultPlan
@@ -236,7 +236,8 @@ class Engine:
         self._step_events: list[tuple[str, dict]] = []
         self._tokens_harvested = 0
 
-        self._prefill, self._segment = _jitted_fns(cfg, ecfg)
+        self._prefill, self._segment = _jitted_fns(cfg, ecfg,
+                                                   ops.current_backend())
 
     # -- public API ---------------------------------------------------------
 
@@ -766,32 +767,43 @@ class Engine:
 # by parameter name and resolved to positions via signature inspection —
 # the static analyzer (repro.analysis.invariance, TPP303) re-derives the
 # positions and rejects a declaration that would donate a live input such
-# as the weights.  BOUND_ARGS is the (cfg, ecfg) prefix partial-applied
-# before jit; donate_argnums are relative to the remaining parameters.
+# as the weights.  BOUND_ARGS is the (cfg, ecfg) prefix bound before jit;
+# donate_argnums are relative to the remaining parameters.
 DONATED_ARGS = ("caches", "state")
 BOUND_ARGS = 2
 
 
 def donation_argnums(fn, *, bound: int = BOUND_ARGS) -> tuple[int, ...]:
     """Positions of :data:`DONATED_ARGS` in ``fn``'s signature, shifted by
-    the ``bound`` partial-applied leading parameters."""
+    the ``bound`` leading parameters bound before jit."""
     import inspect
     params = list(inspect.signature(fn).parameters)
     return tuple(params.index(name) - bound for name in DONATED_ARGS)
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_fns(cfg: ModelConfig, ecfg: EngineConfig):
-    """One (prefill, segment) jit pair per (model, engine) config — shared
-    across Engine instances so a fresh engine reuses compiled code."""
+def _jitted_fns(cfg: ModelConfig, ecfg: EngineConfig, backend: str):
+    """One (prefill, segment) jit pair per (model, engine config, kernel
+    backend) — shared across Engine instances so a fresh engine reuses
+    compiled code.  The kernel backend is read while tracing, so it is part
+    of the key and pinned around each trace: an engine built under one
+    backend never runs a program traced under another."""
     # donation saves a cache copy per call on accelerators; XLA:CPU warns
     # and ignores it, so only request it off-CPU
     on_cpu = jax.default_backend() == "cpu"
+
+    def pinned(fn):
+        def body(*args):
+            with ops.use_backend(backend):
+                return fn(cfg, ecfg, *args)
+        body.__name__ = fn.__name__
+        return body
+
     segment = jax.jit(
-        partial(_decode_segment, cfg, ecfg),
+        pinned(_decode_segment),
         donate_argnums=() if on_cpu else donation_argnums(_decode_segment))
     prefill = jax.jit(
-        partial(_prefill_one, cfg, ecfg),
+        pinned(_prefill_one),
         donate_argnums=() if on_cpu else donation_argnums(_prefill_one))
     return prefill, segment
 

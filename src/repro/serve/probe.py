@@ -9,9 +9,10 @@ spec.  Two probe levels:
   cache + params and compare bytes against the budget — instant, no
   compilation.
 - ``trial(..., execute=True)``: additionally jit-compile and run one real
-  prefill + decode step at the candidate shape on dummy data, catching
-  allocation/compile failures — the authoritative check (slower; the
-  engine's ``probe=True`` startup path uses it once).
+  decode step at the candidate shape on dummy data; an out-of-memory
+  error (``RESOURCE_EXHAUSTED``, at compile or at run time) means "does
+  not fit", and every other error propagates — the authoritative check
+  (slower; the engine's ``probe=True`` startup path uses it once).
 
 The binary search assumes monotonicity (if B slots fit, so do B-1), which
 holds for both probe levels.
@@ -87,7 +88,9 @@ def trial(cfg: ModelConfig, spec: BatchSpec, *,
         logits, _ = step(params, caches, tokens, pos, page_table=table)
         jax.block_until_ready(logits)
         return True
-    except Exception:            # RESOURCE_EXHAUSTED / XLA compile failure
+    except jax.errors.JaxRuntimeError as exc:
+        if "RESOURCE_EXHAUSTED" not in str(exc):
+            raise                # a compile failure is not "does not fit"
         return False
 
 

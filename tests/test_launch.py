@@ -1,6 +1,12 @@
 """Launch-layer units: roofline HLO parsing, shapes table, report rendering,
-ring-buffer KV cache exactness (the §Perf H3 optimization)."""
+ring-buffer KV cache exactness (the §Perf H3 optimization), placement of
+the persistent compilation cache."""
 import dataclasses
+import os
+import pathlib
+import subprocess
+import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -97,3 +103,40 @@ def test_report_renders(tmp_path):
     r = report.roofline_table(recs)
     assert "memory" in r
     assert "2 cells" in report.summary(recs)
+
+
+_REPO = pathlib.Path(__file__).resolve().parent.parent
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_lands_in_one_fixed_place(tmp_path, env_dir):
+    """With ``JAX_COMPILATION_CACHE_DIR`` set, entries land only there;
+    without it, in ``<checkout>/.jax_cache`` (redirected to ``tmp_path``
+    here so the test leaves the checkout untouched).  A fresh process, so
+    the cache JAX initialises once per process starts clean."""
+    from repro.launch import compile_cache
+    assert compile_cache.CHECKOUT_CACHE_DIR == _REPO / ".jax_cache"
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = str(_REPO / "src")
+    checkout_dir, var_dir = tmp_path / "checkout", tmp_path / "var"
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(var_dir)
+    code = f"""
+        import pathlib
+        import jax, jax.numpy as jnp
+        from repro.launch import compile_cache
+        compile_cache.CHECKOUT_CACHE_DIR = pathlib.Path({str(checkout_dir)!r})
+        print(compile_cache.enable_compile_cache())
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.block_until_ready(jax.jit(lambda x: jnp.sin(x) @ x)(
+            jnp.ones((32, 32))))
+    """
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                         capture_output=True, text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-4000:]
+    used, unused = ((var_dir, checkout_dir) if env_dir
+                    else (checkout_dir, var_dir))
+    assert out.stdout.strip() == str(used)
+    assert any(used.iterdir())
+    assert not unused.exists()

@@ -23,7 +23,8 @@ from repro.configs import get_config
 from repro.kernels import ops
 from repro.models import lm
 from repro.serve import (Engine, EngineConfig, PagedKvCache, Request,
-                         Scheduler, ServeConfig, generate, generate_loop)
+                         RequestStatus, Scheduler, ServeConfig, generate,
+                         generate_loop)
 from repro.serve.kvcache import pages_needed
 from repro.serve.probe import BatchSpec, max_feasible_slots, trial
 from repro.serve.sampling import sample_tokens
@@ -374,6 +375,28 @@ def test_engine_backend_invariance():
     assert outs[0] == outs[1]
 
 
+def test_engine_programs_keyed_by_backend():
+    """An engine runs programs traced under the kernel backend it was built
+    with: same config, other backend → other programs; the backend active
+    when the engine later runs does not leak into them."""
+    cfg, params = _model("minicpm_2b")
+    ecfg = EngineConfig(num_slots=2, page_size=4, max_seq=16)
+    xla_eng = Engine(cfg, params, ecfg)
+    with ops.use_backend("pallas_interpret"):
+        pallas_eng = Engine(cfg, params, ecfg)
+    assert pallas_eng._prefill is not xla_eng._prefill
+    assert Engine(cfg, params, ecfg)._segment is xla_eng._segment
+    with ops.use_backend("pallas_interpret"):
+        xla_eng.submit([1, 2, 3], 2, uid=0)
+        text = xla_eng._prefill.lower(
+            params, xla_eng.caches, xla_eng._state,
+            jnp.zeros((1, 8), jnp.int32), jnp.asarray(xla_eng.kv.table()[:1]),
+            jnp.int32(3), jnp.int32(0), jnp.uint32(0), jnp.uint32(0),
+            jnp.float32(0), jnp.int32(0), jnp.float32(1), jnp.int32(2),
+            jnp.uint32(0), jnp.int32(0)).as_text()
+    assert "pallas_call" not in text
+
+
 def test_engine_eos_stops_early():
     cfg, params = _model("minicpm_2b")
     rng = np.random.default_rng(0)
@@ -432,6 +455,28 @@ def test_probe_trial_and_binary_search():
         max_feasible_slots(cfg, page_size=4, max_seq=32, budget_bytes=1)
 
 
+def test_probe_reraises_errors_other_than_out_of_memory(monkeypatch):
+    """Only RESOURCE_EXHAUSTED reads as "does not fit"; a compile or
+    program error propagates instead of silently shrinking the engine."""
+    import repro.serve.probe as probe_mod
+    cfg, _ = _model("minicpm_2b")
+    good = BatchSpec(num_slots=2, num_pages=16, page_size=4, max_seq=32)
+
+    def broken(*a, **k):
+        raise ValueError("lowering refused")
+
+    monkeypatch.setattr(probe_mod.lm, "decode_step", broken)
+    with pytest.raises(ValueError, match="lowering refused"):
+        trial(cfg, good, execute=True)
+
+    def oom(*a, **k):
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm")
+
+    monkeypatch.setattr(probe_mod.lm, "decode_step", oom)
+    assert not trial(cfg, good, execute=True)
+
+
 # --------------------------------------------------------------------------
 # Prefill-vs-decode tune split
 # --------------------------------------------------------------------------
@@ -455,20 +500,75 @@ def test_tune_serving_shapes_split_phases(tmp_path):
 # Committed request-trace replay (CI fixture)
 # --------------------------------------------------------------------------
 
+# Float32 reduced model (two layers, contractions of at most 128 terms):
+# the prefill logits are O(1) and float32 rounding moves them by ~1e-6;
+# a jax/XLA upgrade that reassociates those sums stays far below 1e-4,
+# while any change to the model's math moves them by orders more.
+TRACE_LOGITS_ATOL = 1e-4
+
+
+def _trace_pins(cfg, params, reqs, seed):
+    """Per traced request: the last-position prefill logits of its prompt
+    and the first token the sampler draws from them with the request's
+    knobs (uid/position/seed keyed, as the engine draws it).  Regenerate
+    the fixture's ``prefill_logits``/``first_tokens`` from this when the
+    model's math changes on purpose."""
+    logits = []
+    for r in reqs:
+        lg, _ = lm.prefill(cfg, params, None,
+                           {"tokens": jnp.asarray([r["prompt"]], jnp.int32)})
+        logits.append(np.asarray(lg[0], np.float32))
+    return np.stack(logits), _trace_first_tokens(np.stack(logits), reqs, seed)
+
+
+def _trace_first_tokens(logits, reqs, seed):
+    toks = sample_tokens(
+        jnp.asarray(logits, jnp.float32),
+        uids=jnp.asarray([r["uid"] for r in reqs], jnp.uint32),
+        positions=jnp.asarray([len(r["prompt"]) for r in reqs], jnp.int32),
+        seed=jnp.uint32(seed),
+        temperature=jnp.asarray([r["temperature"] for r in reqs],
+                                jnp.float32),
+        top_k=jnp.asarray([r["top_k"] for r in reqs], jnp.int32),
+        top_p=jnp.asarray([r["top_p"] for r in reqs], jnp.float32))
+    return [int(t) for t in toks]
+
+
 def test_serve_trace_replay_fixture():
-    """The committed trace must regenerate bit-identically from its seed,
-    and replaying it through the engine must reproduce the pinned outputs —
-    a cross-commit guard on sampler/schedule determinism."""
+    """The committed trace must regenerate bit-identically from its seed;
+    the model's prefill logits for every traced prompt must match the
+    pinned ones within ``TRACE_LOGITS_ATOL``; the sampler, fed the pinned
+    logits, must draw the pinned first tokens (determinism on fixed
+    logits); and replaying the trace through the engine must finish every
+    request with its whole budget, identically on a second replay — a
+    cross-commit guard that does not pin tokens sampled from random
+    weights, which flip on rounding."""
     fix = json.loads(TRACE_PATH.read_text())
     cfg, params = _model(fix["config"])
     reqs = synth_trace(fix["trace_seed"], fix["num_requests"],
                        cfg.vocab_size)
     assert reqs == fix["requests"], \
         "synth_trace drifted from the committed fixture"
-    eng = Engine(cfg, params, EngineConfig(**fix["engine"]))
+    seed = fix["engine"]["seed"]
+    pinned = np.asarray(fix["prefill_logits"], np.float32)
+    logits, _ = _trace_pins(cfg, params, reqs, seed)
+    np.testing.assert_allclose(logits, pinned, rtol=0,
+                               atol=TRACE_LOGITS_ATOL)
+    assert _trace_first_tokens(pinned, reqs, seed) == fix["first_tokens"]
+
+    def replay():
+        eng = Engine(cfg, params, EngineConfig(**fix["engine"]))
+        for r in reqs:
+            eng.submit(r["prompt"], r["max_new"],
+                       temperature=r["temperature"], top_k=r["top_k"],
+                       top_p=r["top_p"], uid=r["uid"])
+        return eng, eng.run()
+
+    eng, done = replay()
     for r in reqs:
-        eng.submit(r["prompt"], r["max_new"], temperature=r["temperature"],
-                   top_k=r["top_k"], top_p=r["top_p"], uid=r["uid"])
-    done = eng.run()
-    got = {str(uid): toks for uid, toks in done.items()}
-    assert got == fix["outputs"]
+        assert eng.status(r["uid"]) == RequestStatus.FINISHED
+        toks = done[r["uid"]]
+        assert toks[:len(r["prompt"])] == r["prompt"]
+        assert len(toks) == len(r["prompt"]) + r["max_new"]
+        assert all(0 <= t < cfg.vocab_size for t in toks)
+    assert replay()[1] == done

@@ -7,7 +7,8 @@ after every step), unaffected requests bit-identical to a fault-free run,
 and preempted requests resuming bit-identically (the counter-sampler
 payoff).  Plus the lifecycle satellites (duplicate-uid rejection, partial
 results on non-drain, cancel, virtual-clock deadlines) and the fused-kernel
-XLA fallback (``use_fusion=True`` survives a forced Pallas failure).
+opt-in XLA fallback (``use_fusion=True`` survives a forced Pallas failure
+with ``REPRO_FUSION_FALLBACK=1``).
 """
 import dataclasses
 
@@ -358,7 +359,8 @@ def _fused_output_args(m=32, k=64, n=128, seed=0):
             for s in [(m, k), (k, n), (n,), (m, n), (n,), (n,)]]
 
 
-def test_fallback_matches_xla_reference_exactly():
+def test_fallback_matches_xla_reference_exactly(monkeypatch):
+    monkeypatch.setenv("REPRO_FUSION_FALLBACK", "1")
     args = _fused_output_args()
     fusion.lowering._COMPILE_CACHE.clear()
     ref = np.asarray(fusion.fused_output_apply(*args, backend="xla",
@@ -379,7 +381,8 @@ def test_fallback_matches_xla_reference_exactly():
 
 
 def test_fallback_strict_mode_env(monkeypatch):
-    monkeypatch.setenv("REPRO_FUSION_FALLBACK", "0")
+    # strict is the default: no variable, no fallback
+    monkeypatch.delenv("REPRO_FUSION_FALLBACK", raising=False)
     args = _fused_output_args()
     fusion.lowering._COMPILE_CACHE.clear()
     with fusion.force_pallas_failure("fused_output"):
@@ -389,10 +392,12 @@ def test_fallback_strict_mode_env(monkeypatch):
     fusion.lowering._COMPILE_CACHE.clear()
 
 
-def test_fused_engine_survives_forced_pallas_failure():
+def test_fused_engine_survives_forced_pallas_failure(monkeypatch):
     """use_fusion=True generation under a Pallas backend that cannot
-    compile the fused graphs: every affected graph degrades to the XLA
-    reference and the served tokens match the healthy fused run."""
+    compile the fused graphs: with the fallback opted in, every affected
+    graph degrades to the XLA reference and the served tokens match the
+    healthy fused run."""
+    monkeypatch.setenv("REPRO_FUSION_FALLBACK", "1")
     cfg0, params = _model()
     cfg = dataclasses.replace(cfg0, use_fusion=True)
     ecfg = EngineConfig(num_slots=2, page_size=4, max_seq=32, segment_len=4,

@@ -97,7 +97,7 @@ def test_mini_dryrun_train_compiles_on_mesh():
     production dry-run."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_local_mesh
         from functools import partial
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
@@ -107,7 +107,7 @@ def test_mini_dryrun_train_compiles_on_mesh():
         from repro.train.steps import TrainConfig, make_train_step
         import dataclasses
 
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = make_local_mesh(2, 4)
         cfg = dataclasses.replace(get_config("qwen3_moe_235b").reduced(),
                                   d_model=64, num_layers=2)
         rules = TRAIN_RULES(mesh)
@@ -139,7 +139,7 @@ def test_mini_dryrun_train_compiles_on_mesh():
 def test_mini_dryrun_decode_compiles_on_mesh():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_local_mesh
         from functools import partial
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
@@ -148,7 +148,7 @@ def test_mini_dryrun_decode_compiles_on_mesh():
         from repro.models import lm
         from repro.serve.decode import ServeConfig, make_serve_step
 
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = make_local_mesh(2, 4)
         cfg = get_config("gemma3_12b").reduced()
         rules = DECODE_RULES(mesh)
         with mesh, use_rules(rules):
@@ -173,12 +173,11 @@ def test_compressed_psum_shard_map():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp
         import numpy as np
-        from repro.launch.mesh import make_mesh_compat
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import compressed_psum
 
-        mesh = make_mesh_compat((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16) / 7.0
 
         def f(xs, err):
@@ -187,8 +186,9 @@ def test_compressed_psum_shard_map():
 
         err0 = jnp.zeros((8, 16), jnp.float32)
         with mesh:
-            g = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-                          out_specs=(P("data"), P("data")), check_rep=False)
+            g = jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+                              out_specs=(P("data"), P("data")),
+                              check_vma=False)
             out, err = g(x, err0)
         want = np.asarray(x).mean(0)
         got = np.asarray(out[0])
@@ -205,17 +205,17 @@ def test_elastic_checkpoint_restore_across_meshes(tmp_path):
     elastic-rescale path."""
     out = _run_subprocess(f"""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_local_mesh
         import numpy as np
-        from repro.launch.mesh import make_mesh_compat
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import restore_checkpoint, save_checkpoint
 
         tree = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
-        m1 = make_mesh_compat((4, 2), ("data", "model"))
+        m1 = make_local_mesh(4, 2)
         sharded = jax.device_put(tree["w"], NamedSharding(m1, P("data", "model")))
         save_checkpoint(r"{tmp_path}", 7, {{"w": sharded}})
 
-        m2 = make_mesh_compat((2, 4), ("data", "model"))
+        m2 = make_local_mesh(2, 4)
         shd = {{"w": NamedSharding(m2, P("model", "data"))}}
         got, step, _ = restore_checkpoint(r"{tmp_path}", tree, shardings=shd)
         np.testing.assert_array_equal(np.asarray(got["w"]), np.asarray(tree["w"]))
@@ -231,8 +231,8 @@ def test_moe_ep_shard_map_matches_single_device():
     (tokens replicated over model; capacity dropless)."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_local_mesh
         import numpy as np, dataclasses
-        from repro.launch.mesh import make_mesh_compat
         from repro.configs import get_config
         from repro.distributed.sharding import TRAIN_RULES, use_rules
         from repro.models import blocks as B
@@ -246,7 +246,7 @@ def test_moe_ep_shard_map_matches_single_device():
                               jnp.float32)
         y0, aux0 = B.moe_apply(cfg, p, x, ep_axis=None)
 
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = make_local_mesh(2, 4)
         rules = TRAIN_RULES(mesh)
         with mesh, use_rules(rules):
             y1, aux1 = jax.jit(lambda p, x: _moe_maybe_sharded(
