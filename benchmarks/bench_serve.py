@@ -20,12 +20,9 @@ are waste, not throughput).  The report (``BENCH_serve.json``) carries the
 engine's per-step tokens/sec trajectory, per-request TTFT / per-token
 latency histograms, the engine's metrics-registry snapshot, and the per-step
 queue-depth / occupancy / preemption series read back from the flight
-recorder (``docs/observability.md``).  The timed engine run also exports a
-Chrome trace (``BENCH_serve_trace.json``) from the engine's tracing spans —
-``python -m repro.obs.trace --validate`` gates it in ``scripts/check.sh``.
-``--smoke`` runs a reduced model and also gates engine-vs-loop greedy
-parity (same tokens on a uniform batch) — the CI hook in
-``scripts/check.sh``.
+recorder (``docs/observability.md``).  ``--smoke`` runs a reduced model and
+also gates engine-vs-loop greedy parity (same tokens on a uniform batch) —
+the CI hook in ``scripts/check.sh``.
 
 Row format matches the other benchmarks: ``name,usec,extras``.
 """
@@ -44,8 +41,6 @@ JSON_PATH = os.path.join(
     "BENCH_serve.json")
 FAULTS_JSON_PATH = os.path.join(os.path.dirname(JSON_PATH),
                                 "BENCH_serve_faults.json")
-TRACE_JSON_PATH = os.path.join(os.path.dirname(JSON_PATH),
-                               "BENCH_serve_trace.json")
 
 
 def synth_trace(seed: int, n: int, vocab: int, *, plen_lo=4, plen_hi=48,
@@ -67,13 +62,13 @@ def synth_trace(seed: int, n: int, vocab: int, *, plen_lo=4, plen_hi=48,
 
 
 def _run_engine(cfg, params, reqs, *, num_slots, max_seq, seed=0,
-                segment_len=8, tracer=None):
+                segment_len=8):
     from repro.serve import Engine, EngineConfig
     ecfg = EngineConfig(num_slots=num_slots, page_size=16, max_seq=max_seq,
                         segment_len=segment_len, seed=seed)
     # flight capacity sized to hold every step of the run so the per-step
     # queue/occupancy series in the report covers the whole trace
-    eng = Engine(cfg, params, ecfg, tracer=tracer, flight_capacity=4096)
+    eng = Engine(cfg, params, ecfg, flight_capacity=4096)
     for r in reqs:
         eng.submit(r["prompt"], r["max_new"], temperature=r["temperature"],
                    top_k=r["top_k"], top_p=r["top_p"], uid=r["uid"])
@@ -177,12 +172,9 @@ def run(smoke: bool = False):
                  f"batch={num_slots};equal={parity}"))
 
     # -- throughput: warm both paths once, then time -----------------------
-    from repro.obs import trace as obs_trace
     _run_engine(cfg, params, reqs, num_slots=num_slots, max_seq=max_seq)
-    tracer = obs_trace.Tracer()   # explicit tracer → exported Chrome trace
     e_wall, e_tok, traj, ttft, per_tok, _, eng = _run_engine(
-        cfg, params, reqs, num_slots=num_slots, max_seq=max_seq,
-        tracer=tracer)
+        cfg, params, reqs, num_slots=num_slots, max_seq=max_seq)
     _run_static(cfg, params, reqs, num_slots=num_slots, scfg=scfg_time)
     s_wall, s_tok = _run_static(cfg, params, reqs, num_slots=num_slots,
                                 scfg=scfg_time)
@@ -217,13 +209,6 @@ def run(smoke: bool = False):
     }
     with open(JSON_PATH, "w") as f:
         json.dump(report, f, indent=1)
-
-    # Chrome trace of the timed engine run (chrome://tracing / Perfetto);
-    # scripts/check.sh validates its schema via `repro.obs.trace --validate`
-    chrome = obs_trace.chrome_trace(tracer.spans(), t0=tracer.t0,
-                                    process_name="bench_serve")
-    with open(TRACE_JSON_PATH, "w") as f:
-        json.dump(chrome, f, indent=1)
 
     # throughput gate: ragged continuous batching must beat static batching
     # (CI smoke allows a little scheduling noise on shared runners)

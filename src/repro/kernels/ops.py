@@ -12,12 +12,15 @@
 
 Select globally via env ``REPRO_KERNEL_BACKEND``, per-call via ``backend=``,
 or with the ``use_backend`` context manager.
+
+Every entry point runs under ``jax.named_scope("ops.<function>")``, so its
+operations carry that name in a profiler trace whatever backend lowers them.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +28,7 @@ import jax.numpy as jnp
 from repro.kernels import ref as _ref
 
 __all__ = [
-    "current_backend", "use_backend",
+    "current_backend", "use_backend", "scoped",
     "matmul", "attention", "decode_attention", "paged_decode_attention",
     "mamba_scan",
     "block_spmm", "grouped_matmul", "conv2d",
@@ -54,6 +57,20 @@ def _interp(backend):
     return backend == "pallas_interpret"
 
 
+def scoped(name: str):
+    """Decorator: run the function under ``jax.named_scope(name)``, a new
+    scope object per call (one shared ``named_scope`` object keeps its
+    caller's name stack on itself, so it is not re-entrant)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return deco
+
+
+@scoped("ops.matmul")
 def matmul(a, b, *, bias=None, activation=None, out_dtype=None,
            spec_string=None, tiles=None, backend=None):
     backend = backend or current_backend()
@@ -68,6 +85,7 @@ def matmul(a, b, *, bias=None, activation=None, out_dtype=None,
     )
 
 
+@scoped("ops.attention")
 def attention(q, k, v, *, causal=True, window=None, scale=None,
               out_dtype=None, backend=None, block_q=128, block_kv=128):
     backend = backend or current_backend()
@@ -87,6 +105,7 @@ def attention(q, k, v, *, causal=True, window=None, scale=None,
     )
 
 
+@scoped("ops.decode_attention")
 def decode_attention(q, k_cache, v_cache, *, length=None, window=None,
                      out_dtype=None, backend=None, block_kv=128):
     backend = backend or current_backend()
@@ -100,6 +119,7 @@ def decode_attention(q, k_cache, v_cache, *, length=None, window=None,
     )
 
 
+@scoped("ops.paged_decode_attention")
 def paged_decode_attention(q, k_pool, v_pool, page_table, *, page_size,
                            length, window=None, out_dtype=None, backend=None,
                            block_kv=128):
@@ -127,6 +147,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, *, page_size,
     )
 
 
+@scoped("ops.mamba_scan")
 def mamba_scan(x, dt, a, b_in, c_in, d_skip, *, h0=None, out_dtype=None,
                backend=None, chunk=64):
     backend = backend or current_backend()
@@ -144,6 +165,7 @@ def mamba_scan(x, dt, a, b_in, c_in, d_skip, *, h0=None, out_dtype=None,
     )
 
 
+@scoped("ops.block_spmm")
 def block_spmm(blocks, row_id, col_id, b, *, nrows_b, bn=128,
                out_dtype=None, backend=None):
     backend = backend or current_backend()
@@ -157,6 +179,7 @@ def block_spmm(blocks, row_id, col_id, b, *, nrows_b, bn=128,
     )
 
 
+@scoped("ops.grouped_matmul")
 def grouped_matmul(x, group_id, w, *, bf=128, out_dtype=None, backend=None):
     backend = backend or current_backend()
     if backend == "xla":
@@ -167,6 +190,7 @@ def grouped_matmul(x, group_id, w, *, bf=128, out_dtype=None, backend=None):
     )
 
 
+@scoped("ops.conv2d")
 def conv2d(x_nhwc, w_rsck, *, stride=1, out_dtype=None, backend=None):
     backend = backend or current_backend()
     if backend == "xla":

@@ -109,6 +109,7 @@ def init_attention(cfg: ModelConfig, key):
     }
 
 
+@ops.scoped("attention")
 def attention_apply(cfg: ModelConfig, p, x, *, kind: str = "attn",
                     positions=None, cache=None, cache_pos=None,
                     xattn_kv=None, residual=None, dropout_seed=None,
@@ -440,6 +441,7 @@ def init_mlp(cfg: ModelConfig, key, d_ff: Optional[int] = None):
             "bd": jnp.zeros((d,), jnp.float32)}
 
 
+@ops.scoped("mlp")
 def mlp_apply(cfg: ModelConfig, p, x2d):
     """x2d (T, d) → (T, d).  BRGEMM + fused activation epilogue (paper
     §III-A MLP).

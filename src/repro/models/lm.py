@@ -449,7 +449,9 @@ def lm_loss(cfg: ModelConfig, params, batch, *, ep_axis=None, remat=True,
         labels.reshape(b, nchunks, chunk).transpose(1, 2, 0),
         mask.reshape(b, nchunks, chunk).transpose(1, 2, 0),
     )
-    (tot, cnt), _ = jax.lax.scan(body, (jnp.zeros(()), jnp.zeros(())), xs)
+    with jax.named_scope("lm_head"):
+        (tot, cnt), _ = jax.lax.scan(body, (jnp.zeros(()), jnp.zeros(())),
+                                     xs)
     ce = tot / jnp.maximum(cnt, 1.0)
     loss = ce + aux_weight * aux
     return loss, {"ce": ce, "aux": aux, "tokens": cnt}
@@ -566,9 +568,10 @@ def prefill(cfg: ModelConfig, params, caches, batch, *, ep_axis=None,
         h_last = h[:, -1]
     else:
         h_last = h[jnp.arange(h.shape[0]), jnp.asarray(logit_index, jnp.int32)]
-    w = _unembed_weight(cfg, params)
-    logits = h_last.astype(jnp.float32) @ w.astype(jnp.float32)
-    return _mask_pad_logits(cfg, logits), caches
+    with jax.named_scope("lm_head"):
+        w = _unembed_weight(cfg, params)
+        logits = h_last.astype(jnp.float32) @ w.astype(jnp.float32)
+        return _mask_pad_logits(cfg, logits), caches
 
 
 def decode_step(cfg: ModelConfig, params, caches, tokens, pos, *,
@@ -581,10 +584,11 @@ def decode_step(cfg: ModelConfig, params, caches, tokens, pos, *,
                                   cache_pos=pos, ep_axis=ep_axis, remat=False,
                                   unroll=unroll, page_table=page_table,
                                   page_size=page_size)
-    w = _unembed_weight(cfg, params)
-    logits = h[:, -1].astype(jnp.float32) @ w.astype(jnp.float32)
-    logits = constrain(logits, ("batch", "vocab"))
-    return _mask_pad_logits(cfg, logits), caches
+    with jax.named_scope("lm_head"):
+        w = _unembed_weight(cfg, params)
+        logits = h[:, -1].astype(jnp.float32) @ w.astype(jnp.float32)
+        logits = constrain(logits, ("batch", "vocab"))
+        return _mask_pad_logits(cfg, logits), caches
 
 
 def finite_logits(logits) -> jax.Array:

@@ -8,10 +8,10 @@ call per event).  The flight recorder is *not* gated — it is the black box a
 postmortem needs precisely when nobody was watching, and its cost is one
 bounded dict append per engine step.
 
-Submodules: ``trace`` (spans + Chrome export), ``metrics`` (registry +
-catalog), ``recorder`` (flight recorder), ``profiler`` (warmup+median kernel
-timing vs ``perf_model`` predictions), ``report`` (the attribution-table
-CLI: ``python -m repro.obs.report``).  ``profiler``/``report`` import the
+Submodules: ``trace`` (spans, also written into the ``jax.profiler``
+trace), ``metrics`` (registry + catalog), ``recorder`` (flight recorder),
+``profiler`` (warmup+median kernel timing vs ``perf_model`` predictions),
+``report`` (the attribution-table CLI: ``python -m repro.obs.report``).  ``profiler``/``report`` import the
 fusion stack and are loaded lazily so that ``core``/``serve`` modules can
 import ``repro.obs`` without cycles.
 """
@@ -23,15 +23,13 @@ from repro.obs import metrics, recorder, trace
 from repro.obs.metrics import (METRIC_CATALOG, NULL_REGISTRY, Registry,
                                default_registry, set_default_registry)
 from repro.obs.recorder import FlightRecorder
-from repro.obs.trace import (NULL_TRACER, Tracer, chrome_trace, get_tracer,
-                             set_tracer, validate_chrome_trace)
+from repro.obs.trace import NULL_TRACER, Tracer, get_tracer, set_tracer
 
 __all__ = [
     "enabled", "metrics", "trace", "recorder",
     "Registry", "NULL_REGISTRY", "default_registry", "set_default_registry",
     "METRIC_CATALOG",
-    "Tracer", "NULL_TRACER", "get_tracer", "set_tracer", "chrome_trace",
-    "validate_chrome_trace",
+    "Tracer", "NULL_TRACER", "get_tracer", "set_tracer",
     "FlightRecorder",
     "profiler",
 ]

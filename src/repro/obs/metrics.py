@@ -54,9 +54,9 @@ METRIC_CATALOG = {
     "serve.slots.active": "gauge: slots holding a running request",
     "serve.pages.used": "gauge: pages owned by running slots",
     "serve.pages.total": "gauge: page-pool size (constant per engine)",
-    "serve.ttft_s": "histogram: submit → first token, seconds",
-    "serve.token_interval_s": "histogram: inter-token gaps per request, seconds",
     "serve.step_s": "histogram: Engine.step wall time, seconds",
+    "serve.decode_iterations": "counter: iterations the decode segments' while_loops ran",
+    "serve.decode_slot_iterations": "counter: active slots summed over those iterations",
     # fusion compiler (process-global registry)
     "fusion.compile_cache.hits": "counter: compile_for_backend memo hits",
     "fusion.compile_cache.misses": "counter: compile_for_backend memo misses",

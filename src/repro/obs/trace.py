@@ -1,25 +1,26 @@
 """Tracing spans — the timeline view of engine steps, fusion compiles and
-autotune searches, exportable as Chrome-trace/Perfetto JSON.
+autotune searches, on the clock of the ``jax.profiler`` trace.
 
 A :class:`Tracer` records nestable, thread-safe :class:`Span`\\ s on an
 injectable clock (the golden tests drive a fake one).  Nesting is tracked
 per thread: a span opened while another is live on the same thread records
-it as parent, so the exported timeline shows prefill inside admit inside
-step.  Instant events (``Tracer.event``) mark zero-duration occurrences —
-preemptions, fallbacks, fault injections.
+it as parent, so prefill sits inside admit inside step.  Instant events
+(``Tracer.event``) mark zero-duration occurrences — preemptions, fallbacks,
+fault injections.
+
+Every span also opens a ``jax.profiler.TraceAnnotation`` of its name, closed
+with it on the same thread, so a profiler trace (``jax.profiler.trace``)
+shows the engine's phases on its host plane beside the device's operations;
+Perfetto or xprof loads it.  The span's args ride along only while the
+profiler records; with none running a span costs one inactive TraceMe.
 
 Span *names* form a stable taxonomy (``docs/observability.md``):
 ``engine.step`` / ``engine.admit`` / ``engine.prefill`` /
-``engine.decode_segment`` / ``engine.grow`` / ``engine.preempt`` /
-``engine.retire`` / ``fusion.compile`` / ``fusion.lower`` /
-``fusion.fallback`` / ``tune.search``.
+``engine.wait`` / ``engine.grow`` / ``engine.decode_segment`` /
+``engine.harvest`` / ``engine.retire`` / ``engine.preempt`` /
+``fusion.compile`` / ``fusion.lower`` / ``fusion.fallback`` /
+``tune.search``.
 
-Export/convert/validate from the shell::
-
-    python -m repro.obs.trace spans.json -o trace.json   # raw dump → Chrome
-    python -m repro.obs.trace --validate trace.json      # schema check (CI)
-
-Load the Chrome JSON in ``chrome://tracing`` or https://ui.perfetto.dev.
 When observability is disabled (``REPRO_OBS=0``) :func:`get_tracer` returns
 the :data:`NULL_TRACER`, whose ``span``/``event`` are allocation-free no-ops.
 """
@@ -27,14 +28,15 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-import json
 import threading
 import time
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Span", "Tracer", "NullTracer", "NULL_TRACER", "get_tracer",
-    "set_tracer", "chrome_trace", "validate_chrome_trace",
+    "set_tracer",
 ]
 
 
@@ -60,29 +62,39 @@ class Span:
         self.args.update(args)
         return self
 
-    def to_dict(self) -> dict:
-        return {"sid": self.sid, "name": self.name, "cat": self.cat,
-                "start": self.start, "end": self.end, "tid": self.tid,
-                "parent": self.parent, "args": self.args}
+
+def _annotation(name: str, args: dict) -> TraceAnnotation:
+    """An open profiler annotation; it carries ``args`` only while the
+    profiler records (inactive, it would drop them anyway)."""
+    ann = (TraceAnnotation(name, **args)
+           if args and TraceAnnotation.is_enabled()
+           else TraceAnnotation(name))
+    ann.__enter__()
+    return ann
 
 
 class _SpanHandle:
-    """Context manager closing one span; proxies ``set`` for exit-time args."""
+    """Context manager closing one span and its profiler annotation;
+    proxies ``set`` for exit-time args."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_ann")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, ann: TraceAnnotation):
         self._tracer = tracer
         self.span = span
+        self._ann = ann
 
     def set(self, **args) -> "_SpanHandle":
         self.span.set(**args)
+        if TraceAnnotation.is_enabled():
+            self._ann.set_metadata(**args)
         return self
 
     def __enter__(self) -> "_SpanHandle":
         return self
 
     def __exit__(self, *exc) -> None:
+        self._ann.__exit__(None, None, None)
         self._tracer._close(self.span)
 
 
@@ -90,8 +102,9 @@ class Tracer:
     """Thread-safe span recorder on an injectable clock.
 
     ``max_spans`` bounds memory: past the cap new spans are counted as
-    dropped rather than recorded (the trace notes the drop count on
-    export) — a long-lived engine cannot grow a trace without bound."""
+    dropped (``dropped``) rather than kept in memory — a long-lived engine
+    cannot grow its span list without bound.  The profiler's copy of each
+    span is not capped."""
 
     def __init__(self, clock=None, *, max_spans: int = 200_000):
         self._clock = clock if clock is not None else time.perf_counter
@@ -102,7 +115,6 @@ class Tracer:
         self._tids: dict[int, int] = {}      # real thread ident → small tid
         self.max_spans = max_spans
         self.dropped = 0
-        self.t0 = self._clock()
 
     @property
     def enabled(self) -> bool:
@@ -121,8 +133,9 @@ class Tracer:
         return stack[-1] if stack else None
 
     def span(self, name: str, cat: str = "engine", **args) -> _SpanHandle:
-        """Open a span: ``with tracer.span("engine.step", step=3) as sp:``.
-        ``sp.set(...)`` attaches exit-time args."""
+        """Open a span and its profiler annotation:
+        ``with tracer.span("engine.step", step=3) as sp:``.  ``sp.set(...)``
+        attaches exit-time args."""
         sp = Span(sid=next(self._ids), name=name, cat=cat,
                   start=self._clock(), tid=self._tid(),
                   parent=self._parent(), args=dict(args))
@@ -130,7 +143,7 @@ class Tracer:
         if stack is None:
             stack = self._stack.open = []
         stack.append(sp.sid)
-        return _SpanHandle(self, sp)
+        return _SpanHandle(self, sp, _annotation(name, sp.args))
 
     def _close(self, sp: Span) -> None:
         sp.end = self._clock()
@@ -146,7 +159,10 @@ class Tracer:
                 self.dropped += 1
 
     def event(self, name: str, cat: str = "engine", **args) -> None:
-        """Record an instant (zero-duration) event."""
+        """Record an instant (zero-duration) event; while the profiler
+        records, it also gets a zero-length annotation."""
+        if TraceAnnotation.is_enabled():
+            _annotation(name, args).__exit__(None, None, None)
         t = self._clock()
         sp = Span(sid=next(self._ids), name=name, cat=cat, start=t, end=t,
                   tid=self._tid(), parent=self._parent(), args=dict(args))
@@ -164,14 +180,6 @@ class Tracer:
         with self._lock:
             self._spans.clear()
             self.dropped = 0
-
-    def save(self, path) -> None:
-        """Write the raw span dump (``python -m repro.obs.trace`` converts it
-        to Chrome format)."""
-        with open(path, "w") as f:
-            json.dump({"clock_t0": self.t0, "dropped": self.dropped,
-                       "spans": [s.to_dict() for s in self.spans()]},
-                      f, indent=1)
 
 
 class _NullSpanHandle:
@@ -196,7 +204,6 @@ _NULL_SPAN = _NullSpanHandle()
 class NullTracer:
     """The disabled backend — ``span``/``event`` are allocation-free."""
 
-    t0 = 0.0
     dropped = 0
 
     @property
@@ -214,10 +221,6 @@ class NullTracer:
 
     def clear(self) -> None:
         pass
-
-    def save(self, path) -> None:
-        with open(path, "w") as f:
-            json.dump({"clock_t0": 0.0, "dropped": 0, "spans": []}, f)
 
 
 NULL_TRACER = NullTracer()
@@ -246,131 +249,3 @@ def set_tracer(tracer) -> "Tracer | NullTracer | None":
         prev = _default
         _default = tracer
     return prev
-
-
-# -- Chrome-trace export ----------------------------------------------------
-
-def chrome_trace(spans, *, t0: Optional[float] = None,
-                 process_name: str = "repro") -> dict:
-    """Render spans as Chrome Trace Event Format (the subset Perfetto and
-    chrome://tracing both load): closed spans → complete ``"X"`` events,
-    instants → ``"i"``, timestamps in microseconds relative to ``t0``."""
-    spans = list(spans)
-    if t0 is None:
-        t0 = min((s.start for s in spans), default=0.0)
-    events = [{
-        "ph": "M", "pid": 1, "tid": 0, "name": "process_name",
-        "args": {"name": process_name},
-    }]
-    for s in spans:
-        base = {
-            "name": s.name,
-            "cat": s.cat,
-            "ts": (s.start - t0) * 1e6,
-            "pid": 1,
-            "tid": s.tid,
-            "args": dict(s.args),
-        }
-        if s.end is not None and s.end > s.start:
-            base["ph"] = "X"
-            base["dur"] = (s.end - s.start) * 1e6
-        else:
-            base["ph"] = "i"
-            base["s"] = "t"         # thread-scoped instant
-        events.append(base)
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-_REQUIRED_EVENT_KEYS = ("name", "ph", "ts", "pid", "tid")
-
-
-def validate_chrome_trace(obj) -> list[str]:
-    """Schema check for the subset :func:`chrome_trace` emits.  Returns a
-    list of problems (empty = valid); CI gates on emptiness."""
-    errors = []
-    if not isinstance(obj, dict) or "traceEvents" not in obj:
-        return ["top level must be an object with a 'traceEvents' list"]
-    events = obj["traceEvents"]
-    if not isinstance(events, list):
-        return ["'traceEvents' must be a list"]
-    for i, ev in enumerate(events):
-        if not isinstance(ev, dict):
-            errors.append(f"event {i}: not an object")
-            continue
-        if ev.get("ph") == "M":
-            continue                         # metadata events are free-form
-        for key in _REQUIRED_EVENT_KEYS:
-            if key not in ev:
-                errors.append(f"event {i}: missing key {key!r}")
-        if not isinstance(ev.get("ts"), (int, float)):
-            errors.append(f"event {i}: 'ts' must be numeric")
-        elif ev["ts"] < 0:
-            errors.append(f"event {i}: negative timestamp {ev['ts']}")
-        ph = ev.get("ph")
-        if ph == "X":
-            if not isinstance(ev.get("dur"), (int, float)) or ev["dur"] < 0:
-                errors.append(f"event {i}: complete event needs dur >= 0")
-        elif ph == "i":
-            pass
-        elif ph is not None:
-            errors.append(f"event {i}: unsupported phase {ph!r}")
-        if "args" in ev and not isinstance(ev["args"], dict):
-            errors.append(f"event {i}: 'args' must be an object")
-    try:
-        json.dumps(obj)
-    except (TypeError, ValueError) as exc:
-        errors.append(f"not JSON-serializable: {exc}")
-    return errors
-
-
-def _spans_from_dump(dump: dict) -> list[Span]:
-    return [Span(sid=d["sid"], name=d["name"], cat=d["cat"],
-                 start=d["start"], end=d["end"], tid=d["tid"],
-                 parent=d["parent"], args=d.get("args", {}))
-            for d in dump["spans"]]
-
-
-def main(argv=None) -> int:
-    import argparse
-
-    ap = argparse.ArgumentParser(
-        prog="python -m repro.obs.trace",
-        description="Convert a raw Tracer dump to Chrome-trace JSON, or "
-                    "validate an existing Chrome trace (CI gate).")
-    ap.add_argument("input", nargs="?", help="raw span dump (Tracer.save)")
-    ap.add_argument("-o", "--output", default=None,
-                    help="Chrome-trace output path (default: stdout)")
-    ap.add_argument("--validate", metavar="TRACE", default=None,
-                    help="validate a Chrome-trace JSON file and exit")
-    args = ap.parse_args(argv)
-
-    if args.validate is not None:
-        with open(args.validate) as f:
-            obj = json.load(f)
-        errors = validate_chrome_trace(obj)
-        n = len([e for e in obj.get("traceEvents", ())
-                 if isinstance(e, dict) and e.get("ph") != "M"])
-        if errors:
-            for e in errors:
-                print(f"INVALID: {e}")
-            return 1
-        print(f"valid Chrome trace: {n} events")
-        return 0
-
-    if args.input is None:
-        ap.error("need a raw span dump to convert (or --validate)")
-    with open(args.input) as f:
-        dump = json.load(f)
-    trace = chrome_trace(_spans_from_dump(dump))
-    out = json.dumps(trace, indent=1)
-    if args.output:
-        with open(args.output, "w") as f:
-            f.write(out)
-        print(f"wrote {args.output} ({len(trace['traceEvents'])} events)")
-    else:
-        print(out)
-    return 0
-
-
-if __name__ == "__main__":
-    raise SystemExit(main())

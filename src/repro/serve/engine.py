@@ -218,6 +218,8 @@ class Engine:
         self._c_preempt = reg.counter("serve.preemptions")
         self._c_grows = reg.counter("serve.page_grows")
         self._c_dumps = reg.counter("serve.flight_dumps")
+        self._c_iters = reg.counter("serve.decode_iterations")
+        self._c_slot_iters = reg.counter("serve.decode_slot_iterations")
         self._c_submitted = reg.counter("serve.requests.submitted")
         self._term_counters = {
             RequestStatus.FINISHED: reg.counter("serve.requests.finished"),
@@ -230,11 +232,13 @@ class Engine:
         self._g_pages_used = reg.gauge("serve.pages.used")
         self._g_pages_total = reg.gauge("serve.pages.total")
         self._g_pages_total.set(num_pages)
-        self._h_ttft = reg.histogram("serve.ttft_s")
-        self._h_tok = reg.histogram("serve.token_interval_s")
         self._h_step = reg.histogram("serve.step_s")
         self._step_events: list[tuple[str, dict]] = []
         self._tokens_harvested = 0
+        # what the decode segments' while_loops report: iterations run, and
+        # active slots summed over them (plain ints, like tokens_generated)
+        self.decode_iterations = 0
+        self.decode_slot_iterations = 0
 
         self._prefill, self._segment = _jitted_fns(cfg, ecfg,
                                                    ops.current_backend())
@@ -342,7 +346,10 @@ class Engine:
         """One continuous-batching iteration.  Returns the uids that
         reached a terminal status during this step.
 
-        Each step opens an ``engine.step`` span, updates the queue/pool
+        Each step opens an ``engine.step`` span around its phases' spans
+        (``engine.admit`` ⊃ ``engine.prefill`` ⊃ ``engine.wait``,
+        ``engine.retire``, ``engine.grow``, ``engine.decode_segment`` ⊃
+        ``engine.wait``, ``engine.harvest``), updates the queue/pool
         gauges, and appends one record (this step's scheduler decisions) to
         the flight recorder."""
         idx = self._step_idx
@@ -379,11 +386,15 @@ class Engine:
                               step=idx)
             self._step_events.append(("fault_exhausted", {}))
         if not blocked:
-            newly += self._fail_impossible_heads()
-            for slot, req in self.sched.admit():
-                failed_uid = self._admit(slot, req)
-                if failed_uid is not None:
-                    newly.append(failed_uid)
+            with self.tracer.span("engine.admit",
+                                  waiting=self.sched.num_waiting) as sp:
+                newly += self._fail_impossible_heads()
+                admitted = self.sched.admit()
+                for slot, req in admitted:
+                    failed_uid = self._admit(slot, req)
+                    if failed_uid is not None:
+                        newly.append(failed_uid)
+                sp.set(admitted=len(admitted))
         newly += self._retire_done()
         self._ensure_segment_pages(grow_allowed=not blocked)
         if any(not self._done[s] for s in self.sched.running):
@@ -502,9 +513,6 @@ class Engine:
         counter = self._term_counters.get(status)
         if counter is not None:
             counter.inc()
-        times = m["token_times"]
-        for prev, cur in zip(times, times[1:]):
-            self._h_tok.observe(cur - prev)
 
     def _deactivate_slot(self, slot: int) -> None:
         self._state = self._state._replace(
@@ -608,7 +616,8 @@ class Engine:
                 jnp.int32(req.top_k), jnp.float32(req.top_p),
                 jnp.int32(req.max_new), self._poison_uid, self._poison_pos)
             self._table_dirty = True
-            first, was_bad = (int(v) for v in jax.device_get(tok_bad))
+            with self.tracer.span("engine.wait"):
+                first, was_bad = (int(v) for v in jax.device_get(tok_bad))
         uid = req.uid
         self._step_events.append(("admit", {"uid": uid, "slot": slot,
                                             "plen": plen}))
@@ -627,7 +636,6 @@ class Engine:
         m = self.metrics[uid]
         if m["first_token"] is None:
             m["first_token"] = now
-            self._h_ttft.observe(now - m["submitted"])
         m["token_times"].append(now)
         m["status"] = RequestStatus.RUNNING
         eos_hit = (self.ecfg.eos_token is not None
@@ -642,6 +650,10 @@ class Engine:
         youngest running request and retries — decoding past a slot's owned
         pages would silently drop KV into the trash page, so coverage is a
         hard precondition for the segment."""
+        with self.tracer.span("engine.grow"):
+            self._grow_pages(grow_allowed)
+
+    def _grow_pages(self, grow_allowed: bool) -> None:
         seg = self.ecfg.segment_len
         order = sorted(self.sched.running,
                        key=self.sched.admitted_seq.__getitem__)
@@ -688,27 +700,35 @@ class Engine:
                            and self.sched.num_waiting > 0)
         with self.tracer.span("engine.decode_segment",
                               slots=len(self.sched.running)) as sp:
-            self.caches, self._state, out = self._segment(
+            self.caches, self._state, out, iters, slot_iters = self._segment(
                 self.params, self.caches, self._state, self._table_dev,
                 self._seed, refill, self._poison_uid, self._poison_pos)
             # ONE host sync per segment: everything the host bookkeeping needs
-            gen_after, still_active, bad, out = jax.device_get(
-                (self._state.gen, self._state.active, self._state.bad, out))
-        now = self._now()
-        harvested = 0
-        for slot in self.sched.running:
-            n_new = int(gen_after[slot] - self._gen[slot])
-            if n_new:
-                uid = int(self._uids[slot])
-                toks = [int(t) for t in out[slot, :n_new]]
-                self._out[uid].extend(toks)
-                self.metrics[uid]["token_times"].extend([now] * n_new)
-                harvested += n_new
-        sp.set(tokens=harvested)
-        self._tokens_harvested += harvested
-        self._c_tokens.inc(harvested)
-        self._gen = gen_after.copy()
-        self._done |= running & ~still_active & ~bad
+            with self.tracer.span("engine.wait"):
+                gen_after, still_active, bad, out, iters, slot_iters = \
+                    jax.device_get((self._state.gen, self._state.active,
+                                    self._state.bad, out, iters, slot_iters))
+        with self.tracer.span("engine.harvest") as hp:
+            now = self._now()
+            harvested = 0
+            for slot in self.sched.running:
+                n_new = int(gen_after[slot] - self._gen[slot])
+                if n_new:
+                    uid = int(self._uids[slot])
+                    toks = [int(t) for t in out[slot, :n_new]]
+                    self._out[uid].extend(toks)
+                    self.metrics[uid]["token_times"].extend([now] * n_new)
+                    harvested += n_new
+            hp.set(tokens=harvested)
+            sp.set(tokens=harvested)
+            self._tokens_harvested += harvested
+            self._c_tokens.inc(harvested)
+            self.decode_iterations += int(iters)
+            self.decode_slot_iterations += int(slot_iters)
+            self._c_iters.inc(int(iters))
+            self._c_slot_iters.inc(int(slot_iters))
+            self._gen = gen_after.copy()
+            self._done |= running & ~still_active & ~bad
         return running & bad
 
     def _quarantine(self, bad: np.ndarray) -> list[int]:
@@ -729,9 +749,12 @@ class Engine:
         return failed
 
     def _retire_done(self) -> list[int]:
+        done = [slot for slot in self.sched.running if self._done[slot]]
+        if not done:
+            return []
         finished = []
-        for slot in list(self.sched.running):
-            if self._done[slot]:
+        with self.tracer.span("engine.retire", slots=len(done)):
+            for slot in done:
                 req = self.sched.retire(slot)
                 self._done[slot] = False
                 self._table_dirty = True
@@ -854,17 +877,20 @@ def _decode_segment(cfg, ecfg, params, caches, state, table, seed, refill,
     engine step instead of idling out the segment.  A slot whose logits go
     non-finite (organically, or via the fault plan's ``poison_*``
     injection) is flagged ``bad``, contributes no token, and stops
-    advancing — the other slots keep decoding."""
+    advancing — the other slots keep decoding.  Also returns the
+    iterations the loop ran and the active slots summed over them (int32
+    scalars)."""
     seg = ecfg.segment_len
     b = state.tok.shape[0]
     out0 = jnp.full((b, seg), -1, jnp.int32)
 
     def cond(c):
-        t, _, st, _, finished_any = c
+        t, _, st, _, finished_any, _ = c
         return (t < seg) & jnp.any(st.active) & ~(refill & finished_any)
 
     def body(c):
-        t, caches, st, out, finished_any = c
+        t, caches, st, out, finished_any, slot_iters = c
+        slot_iters = slot_iters + jnp.sum(st.active, dtype=jnp.int32)
         tok_in = jnp.where(st.active, st.tok, 0)
         logits, caches = lm.decode_step(
             cfg, params, caches, tok_in, st.pos, ep_axis=ecfg.ep_axis,
@@ -888,8 +914,9 @@ def _decode_segment(cfg, ecfg, params, caches, state, table, seed, refill,
             pos=st.pos + alive.astype(jnp.int32),
             gen=gen, active=alive & ~done, bad=st.bad | bad_now)
         return (t + 1, caches, st, out,
-                finished_any | jnp.any(done) | jnp.any(bad_now))
+                finished_any | jnp.any(done) | jnp.any(bad_now), slot_iters)
 
-    _, caches, st, out, _ = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), caches, state, out0, jnp.bool_(False)))
-    return caches, st, out
+    iters, caches, st, out, _, slot_iters = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), caches, state, out0, jnp.bool_(False),
+                     jnp.int32(0)))
+    return caches, st, out, iters, slot_iters

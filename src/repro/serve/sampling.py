@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.fusion import rng
+from repro.kernels.ops import scoped
 
 __all__ = ["SAMPLER_SALT", "sample_tokens"]
 
@@ -56,6 +57,7 @@ def _filter_logits(logits, top_k, top_p):
     return jnp.where(keep, logits, -jnp.inf)
 
 
+@scoped("sample")
 def sample_tokens(logits, *, uids, positions, seed, temperature, top_k,
                   top_p):
     """→ (B,) int32 next tokens.

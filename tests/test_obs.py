@@ -1,7 +1,9 @@
-"""Observability layer tests: tracer spans + Chrome export, metrics
-registry + kill switch, kernel-profiler drift goldens (scripted clock),
-flight-recorder fault dumps (chaos + drain + validate), tunecache counters,
-and the ``Engine.stats`` preemption-skew regression.
+"""Observability layer tests: tracer spans and their copies in the
+``jax.profiler`` trace, engine phase spans and decode counters, named scopes
+in the compiled decode segment, metrics registry + kill switch,
+kernel-profiler drift goldens (scripted clock), flight-recorder fault
+dumps (chaos + drain + validate), tunecache counters, and the
+``Engine.stats`` preemption-skew regression.
 
 The engine-backed tests reuse the shapes of ``test_serve_faults`` so the
 lru-cached jitted step functions compile once per session.
@@ -17,13 +19,11 @@ import pytest
 import repro.obs as obs
 from repro.obs import metrics as obs_metrics
 from repro.obs import profiler as obs_profiler
-from repro.obs import trace as obs_trace
 from repro.obs.metrics import (METRIC_CATALOG, NULL_REGISTRY, NullRegistry,
                                Registry, default_registry,
                                set_default_registry)
 from repro.obs.recorder import FlightRecorder
-from repro.obs.trace import (NULL_TRACER, Tracer, chrome_trace, get_tracer,
-                             set_tracer, validate_chrome_trace)
+from repro.obs.trace import NULL_TRACER, Tracer, get_tracer, set_tracer
 
 
 class FakeClock:
@@ -89,33 +89,63 @@ def test_tracer_bounded_and_clear():
     assert tr.spans() == [] and tr.dropped == 0
 
 
-def test_chrome_trace_schema():
-    tr = Tracer(clock=FakeClock(tick=0.5))
-    with tr.span("engine.step", step=0):
-        tr.event("engine.preempt", uid=3)
-    doc = chrome_trace(tr.spans(), t0=tr.t0, process_name="test")
-    assert validate_chrome_trace(doc) == []
-    phs = {e["ph"] for e in doc["traceEvents"]}
-    assert {"X", "i", "M"} <= phs
-    json.dumps(doc)                              # schema is JSON-serializable
-    # corrupt one required field → validator reports it
-    bad = json.loads(json.dumps(doc))
-    x_ev = next(e for e in bad["traceEvents"] if e["ph"] == "X")
-    del x_ev["ts"]
-    assert validate_chrome_trace(bad)
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under ``jax.profiler.trace`` on the CPU; → the host
+    plane's events as {name: [event]}."""
+    import jax
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    pb = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    events = {}
+    for plane in ProfileData.from_file(str(pb)).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append(e)
+    return events
 
 
-def test_trace_cli_roundtrip(tmp_path):
-    tr = Tracer(clock=FakeClock())
-    with tr.span("a"):
+def test_span_is_a_profiler_host_event(tmp_path):
+    tr = Tracer()
+
+    def spans():
+        with tr.span("engine.step", step=3) as sp:
+            with tr.span("engine.wait"):
+                pass
+            sp.set(terminal=1)
+        tr.event("engine.preempt", uid=2)
+
+    events = _profiled(tmp_path, spans)
+    step, = events["engine.step"]
+    wait, = events["engine.wait"]
+    assert dict(step.stats)["step"] == 3
+    assert dict(step.stats)["terminal"] == 1
+    assert step.start_ns <= wait.start_ns
+    assert wait.start_ns + wait.duration_ns <= \
+        step.start_ns + step.duration_ns
+    assert "engine.preempt" in events
+    # the in-memory copy is unchanged
+    assert [s.name for s in tr.spans()] == ["engine.wait", "engine.step",
+                                            "engine.preempt"]
+    assert tr.spans()[1].args == {"step": 3, "terminal": 1}
+
+
+def test_span_without_profiler_records_nothing_there(tmp_path):
+    from jax.profiler import TraceAnnotation
+    tr = Tracer()
+    assert not TraceAnnotation.is_enabled()
+    with tr.span("engine.before", uid=1):
         pass
-    raw = tmp_path / "raw.json"
-    out = tmp_path / "chrome.json"
-    tr.save(raw)
-    assert obs_trace.main([str(raw), "-o", str(out)]) == 0
-    assert obs_trace.main(["--validate", str(out)]) == 0
-    (tmp_path / "broken.json").write_text('{"traceEvents": [{"ph": "X"}]}')
-    assert obs_trace.main(["--validate", str(tmp_path / "broken.json")]) == 1
+
+    def during():
+        with tr.span("engine.during"):
+            pass
+
+    events = _profiled(tmp_path, during)
+    assert "engine.during" in events and "engine.before" not in events
+    assert [s.name for s in tr.spans()] == ["engine.before",
+                                            "engine.during"]
 
 
 # ---------------------------------------------------------------------------
@@ -339,10 +369,11 @@ def test_engine_chaos_dump_and_stats_skew():
     assert 1 in dump["context"]["uids"]
     assert dump["records"], "dump carried no step records"
     assert eng.registry.snapshot()["serve.flight_dumps"] >= 1
-    # spans made it to the engine's tracer and export cleanly
+    # spans made it to the engine's tracer, every one closed
     names = {s.name for s in tracer.spans()}
     assert "engine.step" in names and "engine.prefill" in names
-    assert validate_chrome_trace(chrome_trace(tracer.spans())) == []
+    assert all(s.end is not None and s.end >= s.start
+               for s in tracer.spans())
     # drained engine: corrupting host state must dump on validate()
     eng._done[0] = True
     with pytest.raises(AssertionError):
@@ -382,6 +413,106 @@ def test_engine_obs_disabled_still_serves(monkeypatch):
         assert eng.flight.steps_recorded > 0   # black box is never gated
     finally:
         set_tracer(old_tr)
+
+
+def test_engine_step_spans_nest():
+    """One step that admits, decodes and retires: engine.step ⊃
+    engine.admit ⊃ engine.prefill ⊃ engine.wait, then engine.grow,
+    engine.decode_segment ⊃ engine.wait, engine.harvest, engine.retire;
+    engine.prefill opens before the prefill program is dispatched."""
+    tracer = Tracer()
+    eng = _engine(tracer=tracer)
+    dispatched = []
+    prefill = eng._prefill
+
+    def timed_prefill(*args):
+        dispatched.append(time.perf_counter())
+        return prefill(*args)
+
+    eng._prefill = timed_prefill
+    eng.submit([1, 2, 3, 4], 3, uid=0)
+    eng.step()
+    spans = tracer.spans()
+    by_sid = {s.sid: s for s in spans}
+
+    def parent(s):
+        return by_sid[s.parent].name if s.parent is not None else None
+
+    step, = [s for s in spans if s.name == "engine.step"]
+    admit, = [s for s in spans if s.name == "engine.admit"]
+    pre, = [s for s in spans if s.name == "engine.prefill"]
+    grow, = [s for s in spans if s.name == "engine.grow"]
+    seg, = [s for s in spans if s.name == "engine.decode_segment"]
+    harvest, = [s for s in spans if s.name == "engine.harvest"]
+    retire, = [s for s in spans if s.name == "engine.retire"]
+    waits = [s for s in spans if s.name == "engine.wait"]
+    assert parent(step) is None
+    assert parent(admit) == parent(grow) == parent(seg) == \
+        parent(harvest) == parent(retire) == "engine.step"
+    assert parent(pre) == "engine.admit"
+    assert sorted(parent(w) for w in waits) == ["engine.decode_segment",
+                                                "engine.prefill"]
+    assert admit.end <= grow.start <= seg.start
+    assert seg.end <= harvest.start <= harvest.end <= retire.start
+    assert pre.start <= dispatched[0] <= pre.end
+    assert admit.args == {"waiting": 1, "admitted": 1}
+    assert pre.args["uid"] == 0
+    assert seg.args["tokens"] == harvest.args["tokens"] == 2
+    assert retire.args == {"slots": 1}
+    assert step.args == {"step": 0, "terminal": 1}
+
+
+def test_decode_counters_match_harvested_tokens():
+    """The segment's own counts — while_loop iterations and active slots
+    summed over them — equal what the harvested tokens say, step by step,
+    with refills cutting segments short."""
+    eng = _engine()
+    rng = np.random.default_rng(3)
+    for uid in range(7):
+        eng.submit(rng.integers(1, 50, size=3 + uid).tolist(),
+                   [2, 9, 5, 3, 7, 4, 6][uid], uid=uid)
+    cut_short = 0
+    while not eng.idle:
+        before = {r.uid: len(eng.metrics[r.uid]["token_times"])
+                  for r in eng.sched.running.values()}
+        it0, si0 = eng.decode_iterations, eng.decode_slot_iterations
+        newly = eng.step()
+        per_slot = []
+        for uid in set(before) | set(newly) | {
+                r.uid for r in eng.sched.running.values()}:
+            n_after = len(eng.metrics[uid]["token_times"])
+            # a request admitted in this step brought its prefill token
+            n_before = before.get(uid, 1 if n_after else 0)
+            per_slot.append(n_after - n_before)
+        iters = eng.decode_iterations - it0
+        assert iters == max(per_slot, default=0)
+        assert eng.decode_slot_iterations - si0 == sum(per_slot)
+        if 0 < iters < eng.ecfg.segment_len and eng.sched.num_waiting:
+            cut_short += 1
+    assert cut_short
+    snap = eng.registry.snapshot()
+    assert snap["serve.decode_iterations"] == eng.decode_iterations
+    assert snap["serve.decode_slot_iterations"] == \
+        eng.decode_slot_iterations == eng.tokens_generated - 7
+
+
+def test_decode_segment_carries_named_scopes():
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.kernels import ops
+    from repro.serve import engine as engine_mod
+    eng = _engine()
+    _, segment = engine_mod._jitted_fns(eng.cfg, eng.ecfg,
+                                        ops.current_backend())
+    text = segment.lower(
+        eng.params, eng.caches, eng._state, eng._table_dev, eng._seed,
+        jnp.bool_(False), eng._poison_uid, eng._poison_pos).compile().as_text()
+    scopes = {c for path in re.findall(r'op_name="([^"]*)"', text)
+              for c in re.split(r"[/;]", path)}
+    assert {"ops.paged_decode_attention", "attention", "mlp", "lm_head",
+            "sample"} <= scopes
 
 
 def test_kvcache_occupancy_and_scheduler_snapshot():
