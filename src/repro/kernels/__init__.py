@@ -3,6 +3,7 @@
 #   brgemm.py          — BRGEMM TPP on the MXU, PARLOOPER-scheduled grid
 #   block_spmm.py      — BCSR work-list block-sparse × dense (+ MoE grouped matmul)
 #   flash_attention.py — fused attention (prefill + decode), GQA/causal/window
+#   paged_attention.py — single-token decode over the live pages of paged KV pools
 #   mamba_scan.py      — chunked selective scan (state resident in VMEM)
 #   conv.py            — Listing-4 direct convolution (executor + 1×1 Pallas path)
 from repro.kernels import ops, ref

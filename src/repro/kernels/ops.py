@@ -13,6 +13,12 @@
 Select globally via env ``REPRO_KERNEL_BACKEND``, per-call via ``backend=``,
 or with the ``use_backend`` context manager.
 
+One entry point leaves ``"xla"`` on a TPU: ``paged_decode_attention`` lowers
+to its Pallas kernel there under ``"xla"`` and ``"pallas"`` alike, and to
+the reference elsewhere (``lax.platform_dependent``).  No XLA formulation of
+paged decode reads only the live pages: each gathers every slot's whole
+padded view first, which makes it the largest share of a decode step.
+
 Every entry point runs under ``jax.named_scope("ops.<function>")``, so its
 operations carry that name in a profiler trace whatever backend lowers them.
 """
@@ -23,7 +29,6 @@ import functools
 import os
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 
@@ -121,30 +126,32 @@ def decode_attention(q, k_cache, v_cache, *, length=None, window=None,
 
 @scoped("ops.paged_decode_attention")
 def paged_decode_attention(q, k_pool, v_pool, page_table, *, page_size,
-                           length, window=None, out_dtype=None, backend=None,
-                           block_kv=128):
+                           length, window=None, out_dtype=None, backend=None):
     """Decode attention over token-major page pools (P, page_size, Hk·D)
     indexed by ``page_table`` (B, maxp) — the serving engine's KV layout.
 
-    The XLA path runs the gather in pool layout (no transpose copy); Pallas
-    backends gather the per-slot view to the head-major cache layout and
-    reuse ``flash_decode_pallas`` (the gather is the price of not carrying a
-    dedicated paged kernel per backend)."""
-    backend = backend or current_backend()
-    if backend == "xla":
-        return _ref.paged_decode_attention_ref(
-            q, k_pool, v_pool, page_table, page_size=page_size,
-            length=length, window=window, out_dtype=out_dtype)
-    from repro.kernels.flash_attention import flash_decode_pallas
-    b, maxp = page_table.shape
-    s, hk = maxp * page_size, k_pool.shape[2] // q.shape[-1]
-    # (B, maxp, ps, Hk·D) → (B, Hk, maxp·ps, D)
-    k = jnp.swapaxes(k_pool[page_table].reshape(b, s, hk, -1), 1, 2)
-    v = jnp.swapaxes(v_pool[page_table].reshape(b, s, hk, -1), 1, 2)
-    return flash_decode_pallas(
-        q, k, v, length=length, window=window, block_kv=block_kv,
-        out_dtype=out_dtype, interpret=_interp(backend),
-    )
+    On a TPU this is ``paged_decode_pallas`` whatever the backend says:
+    the kernel reads each slot's live pages in place, where any XLA
+    formulation gathers the whole padded view of every slot first.  The
+    choice is made when the program lowers (``lax.platform_dependent``),
+    so CPU programs keep the reference; ``pallas_interpret`` runs the
+    kernel in interpret mode."""
+    from repro.kernels.paged_attention import paged_decode_pallas
+    kw = dict(page_size=page_size, window=window, out_dtype=out_dtype)
+    if (backend or current_backend()) == "pallas_interpret":
+        return paged_decode_pallas(q, k_pool, v_pool, page_table,
+                                   length=length, interpret=True, **kw)
+
+    def kernel(q, k_pool, v_pool, page_table, length):
+        return paged_decode_pallas(q, k_pool, v_pool, page_table,
+                                   length=length, **kw)
+
+    def reference(q, k_pool, v_pool, page_table, length):
+        return _ref.paged_decode_attention_ref(q, k_pool, v_pool, page_table,
+                                               length=length, **kw)
+
+    return jax.lax.platform_dependent(q, k_pool, v_pool, page_table, length,
+                                      tpu=kernel, default=reference)
 
 
 @scoped("ops.mamba_scan")

@@ -57,6 +57,7 @@ METRIC_CATALOG = {
     "serve.step_s": "histogram: Engine.step wall time, seconds",
     "serve.decode_iterations": "counter: iterations the decode segments' while_loops ran",
     "serve.decode_slot_iterations": "counter: active slots summed over those iterations",
+    "serve.decode_kv_pages": "counter: KV pages the active slots' paged attention read, summed over those iterations",
     # fusion compiler (process-global registry)
     "fusion.compile_cache.hits": "counter: compile_for_backend memo hits",
     "fusion.compile_cache.misses": "counter: compile_for_backend memo misses",

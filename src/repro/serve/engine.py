@@ -220,6 +220,7 @@ class Engine:
         self._c_dumps = reg.counter("serve.flight_dumps")
         self._c_iters = reg.counter("serve.decode_iterations")
         self._c_slot_iters = reg.counter("serve.decode_slot_iterations")
+        self._c_kv_pages = reg.counter("serve.decode_kv_pages")
         self._c_submitted = reg.counter("serve.requests.submitted")
         self._term_counters = {
             RequestStatus.FINISHED: reg.counter("serve.requests.finished"),
@@ -235,10 +236,12 @@ class Engine:
         self._h_step = reg.histogram("serve.step_s")
         self._step_events: list[tuple[str, dict]] = []
         self._tokens_harvested = 0
-        # what the decode segments' while_loops report: iterations run, and
-        # active slots summed over them (plain ints, like tokens_generated)
+        # what the decode segments' while_loops report: iterations run,
+        # active slots summed over them, and the KV pages those slots'
+        # attention reads (plain ints, like tokens_generated)
         self.decode_iterations = 0
         self.decode_slot_iterations = 0
+        self.decode_kv_pages = 0
 
         self._prefill, self._segment = _jitted_fns(cfg, ecfg,
                                                    ops.current_backend())
@@ -700,14 +703,16 @@ class Engine:
                            and self.sched.num_waiting > 0)
         with self.tracer.span("engine.decode_segment",
                               slots=len(self.sched.running)) as sp:
-            self.caches, self._state, out, iters, slot_iters = self._segment(
+            (self.caches, self._state, out, iters, slot_iters,
+             kv_pages) = self._segment(
                 self.params, self.caches, self._state, self._table_dev,
                 self._seed, refill, self._poison_uid, self._poison_pos)
             # ONE host sync per segment: everything the host bookkeeping needs
             with self.tracer.span("engine.wait"):
-                gen_after, still_active, bad, out, iters, slot_iters = \
-                    jax.device_get((self._state.gen, self._state.active,
-                                    self._state.bad, out, iters, slot_iters))
+                (gen_after, still_active, bad, out, iters, slot_iters,
+                 kv_pages) = jax.device_get(
+                    (self._state.gen, self._state.active, self._state.bad,
+                     out, iters, slot_iters, kv_pages))
         with self.tracer.span("engine.harvest") as hp:
             now = self._now()
             harvested = 0
@@ -725,8 +730,10 @@ class Engine:
             self._c_tokens.inc(harvested)
             self.decode_iterations += int(iters)
             self.decode_slot_iterations += int(slot_iters)
+            self.decode_kv_pages += int(kv_pages)
             self._c_iters.inc(int(iters))
             self._c_slot_iters.inc(int(slot_iters))
+            self._c_kv_pages.inc(int(kv_pages))
             self._gen = gen_after.copy()
             self._done |= running & ~still_active & ~bad
         return running & bad
@@ -878,19 +885,23 @@ def _decode_segment(cfg, ecfg, params, caches, state, table, seed, refill,
     non-finite (organically, or via the fault plan's ``poison_*``
     injection) is flagged ``bad``, contributes no token, and stops
     advancing — the other slots keep decoding.  Also returns the
-    iterations the loop ran and the active slots summed over them (int32
-    scalars)."""
+    iterations the loop ran, the active slots summed over them, and the KV
+    pages those slots' paged attention reads, ``ceil((pos + 1) /
+    page_size)`` each, summed the same way (int32 scalars)."""
     seg = ecfg.segment_len
     b = state.tok.shape[0]
     out0 = jnp.full((b, seg), -1, jnp.int32)
 
     def cond(c):
-        t, _, st, _, finished_any, _ = c
+        t, _, st, _, finished_any, _, _ = c
         return (t < seg) & jnp.any(st.active) & ~(refill & finished_any)
 
     def body(c):
-        t, caches, st, out, finished_any, slot_iters = c
+        t, caches, st, out, finished_any, slot_iters, kv_pages = c
         slot_iters = slot_iters + jnp.sum(st.active, dtype=jnp.int32)
+        kv_pages = kv_pages + jnp.sum(
+            jnp.where(st.active, (st.pos + ecfg.page_size) // ecfg.page_size,
+                      0), dtype=jnp.int32)
         tok_in = jnp.where(st.active, st.tok, 0)
         logits, caches = lm.decode_step(
             cfg, params, caches, tok_in, st.pos, ep_axis=ecfg.ep_axis,
@@ -914,9 +925,10 @@ def _decode_segment(cfg, ecfg, params, caches, state, table, seed, refill,
             pos=st.pos + alive.astype(jnp.int32),
             gen=gen, active=alive & ~done, bad=st.bad | bad_now)
         return (t + 1, caches, st, out,
-                finished_any | jnp.any(done) | jnp.any(bad_now), slot_iters)
+                finished_any | jnp.any(done) | jnp.any(bad_now), slot_iters,
+                kv_pages)
 
-    iters, caches, st, out, _, slot_iters = jax.lax.while_loop(
+    iters, caches, st, out, _, slot_iters, kv_pages = jax.lax.while_loop(
         cond, body, (jnp.int32(0), caches, state, out0, jnp.bool_(False),
-                     jnp.int32(0)))
-    return caches, st, out, iters, slot_iters
+                     jnp.int32(0), jnp.int32(0)))
+    return caches, st, out, iters, slot_iters, kv_pages

@@ -14,6 +14,7 @@ from repro.kernels.brgemm import brgemm_blocked_pallas, matmul_pallas, pick_tile
 from repro.kernels.flash_attention import (flash_attention_pallas,
                                            flash_decode_pallas)
 from repro.kernels.mamba_scan import mamba_scan_pallas
+from repro.kernels.paged_attention import paged_decode_pallas
 
 RNG = np.random.default_rng(42)
 
@@ -181,6 +182,39 @@ def test_flash_decode_lengths_and_window():
         want = ref.decode_attention_ref(q, k, v, length=lens, window=window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("pages_per_block", [None, 3],
+                         ids=["derived", "3_of_8"])
+@pytest.mark.parametrize("window", [None, 10], ids=["full", "window10"])
+@pytest.mark.parametrize("h,hk,d", [(2, 2, 64), (16, 1, 128), (8, 2, 64)],
+                         ids=["mha_g1_d64", "gqa_g16_d128", "gqa_g4_d64"])
+def test_paged_decode_pallas_matches_reference(h, hk, d, window,
+                                               pages_per_block):
+    """The paged kernel reads the live pages in place and agrees with the
+    gather reference: lengths 1, one page, one page + 1, a partial last
+    block and the whole table; shuffled, non-contiguous pages; one slot
+    whose table is all trash page; blocks of 3 pages of 8 (a partial last
+    block, and windowed slots that skip their first blocks)."""
+    ps, maxp = 8, 8
+    lens = [1, ps, ps + 1, 21, maxp * ps, 13]
+    b = len(lens)
+    rows = b * maxp + 1                        # the last row is the trash page
+    q = jnp.asarray(RNG.normal(size=(b, h, d)).astype(np.float32))
+    k_pool = jnp.asarray(RNG.normal(size=(rows, ps, hk * d)).astype(np.float32))
+    v_pool = jnp.asarray(RNG.normal(size=(rows, ps, hk * d)).astype(np.float32))
+    table = RNG.permutation(rows - 1)[:b * maxp].reshape(b, maxp)
+    table[-1] = rows - 1
+    table = jnp.asarray(table, jnp.int32)
+    length = jnp.asarray(lens, jnp.int32)
+    out = paged_decode_pallas(q, k_pool, v_pool, table, page_size=ps,
+                              length=length, window=window,
+                              pages_per_block=pages_per_block, interpret=True)
+    want = ref.paged_decode_attention_ref(q, k_pool, v_pool, table,
+                                          page_size=ps, length=length,
+                                          window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_attention_xla_chunked_matches_oracle():

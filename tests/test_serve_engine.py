@@ -375,6 +375,52 @@ def test_engine_backend_invariance():
     assert outs[0] == outs[1]
 
 
+@pytest.mark.parametrize("arch", ["minicpm_2b", "chatglm3_6b"],
+                         ids=["mha", "gqa"])
+def test_engine_paged_kernel_matches_xla(arch):
+    """Greedy tokens under the paged decode kernel (interpret mode) equal
+    the XLA path's, over several segments with slots finishing and
+    refilling."""
+    cfg, params = _model(arch)
+    rng = np.random.default_rng(2)
+    reqs = [(rng.integers(0, cfg.vocab_size, 3 + 2 * i).tolist(), n)
+            for i, n in enumerate([6, 9, 4, 7, 5])]
+    outs = []
+    for backend in ("xla", "pallas_interpret"):
+        with ops.use_backend(backend):
+            eng = Engine(cfg, params, EngineConfig(
+                num_slots=2, page_size=4, max_seq=32, segment_len=3))
+            for uid, (prompt, max_new) in enumerate(reqs):
+                eng.submit(prompt, max_new, uid=uid)
+            outs.append(eng.run())
+    assert outs[0] == outs[1]
+    assert [len(outs[0][uid]) for uid in range(5)] == [
+        len(p) + n for p, n in reqs]
+
+
+def test_decode_kv_pages_counts_live_pages():
+    """``Engine.decode_kv_pages`` is Σ ceil(context / page_size) over the
+    active slot-iterations: a request with a prompt of L tokens that got N
+    tokens decoded N - 1 of them, at contexts L + 1 … L + N - 1."""
+    from repro.obs.metrics import Registry
+    cfg, params = _model("minicpm_2b")
+    ps = 4
+    eng = Engine(cfg, params, EngineConfig(num_slots=2, page_size=ps,
+                                           max_seq=40, segment_len=5),
+                 registry=Registry())
+    rng = np.random.default_rng(4)
+    prompts = {}
+    for uid, max_new in enumerate([9, 3, 12, 6, 1]):
+        prompts[uid] = rng.integers(0, cfg.vocab_size,
+                                    int(rng.integers(2, 14))).tolist()
+        eng.submit(prompts[uid], max_new, uid=uid)
+    out = eng.run()
+    want = sum(-(-(len(p) + g) // ps) for uid, p in prompts.items()
+               for g in range(1, len(out[uid]) - len(p)))
+    assert eng.decode_kv_pages == want
+    assert eng.registry.snapshot()["serve.decode_kv_pages"] == want
+
+
 def test_engine_programs_keyed_by_backend():
     """An engine runs programs traced under the kernel backend it was built
     with: same config, other backend → other programs; the backend active

@@ -4,8 +4,10 @@ The TPU compiler is installed here even without a chip: it compiles for a
 described ``v5e:2x2`` topology, and refuses what the chip would refuse
 (block shapes off the (8, 128) tiling, too much VMEM) — what interpret mode
 cannot show.  Every case runs at minicpm-2b's published widths (d_model
-2304, 36 heads of 64, d_ff 5760, vocab 122753 padded to 122880) and checks
-that the compiled program holds a Pallas kernel (``tpu_custom_call``).
+2304, 36 heads of 64, d_ff 5760, vocab 122753 padded to 122880), the paged
+decode attention at chatglm3-6b's too (2 KV heads of 128 for 32 query
+heads), and checks that the compiled program holds a Pallas kernel
+(``tpu_custom_call``).
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -83,14 +85,25 @@ def test_flash_decode_pallas_compiles(one_chip):
         ((SLOTS, H, MAX_SEQ, HD), BF16), ((SLOTS,), I32))
 
 
-def test_paged_decode_attention_pallas_compiles(one_chip):
-    rows = SLOTS * MAX_SEQ // PAGE + 1          # + the trash page
-    _compile_has_kernel(
+@pytest.mark.parametrize("slots,h,hk,hd", [
+    (6, H, H, HD),        # minicpm-2b: MHA, Hk·D 2304
+    (16, 32, 2, 128),     # chatglm3-6b: GQA g 16, Hk·D 256
+], ids=["minicpm-2b", "chatglm3-6b"])
+def test_paged_decode_attention_pallas_compiles(one_chip, slots, h, hk, hd):
+    """Under the default backend a TPU program takes the paged kernel, and
+    nothing in it has the shape of a gathered per-slot padded view."""
+    maxp = 2048 // PAGE
+    rows = slots * maxp + 1                     # + the trash page
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((slots, h, hd), BF16), ((rows, PAGE, hk * hd), BF16),
+        ((rows, PAGE, hk * hd), BF16), ((slots, maxp), I32),
+        ((slots,), I32))]
+    text = jax.jit(
         lambda q, k, v, table, length: ops.paged_decode_attention(
-            q, k, v, table, page_size=PAGE, length=length, backend="pallas"),
-        one_chip, ((SLOTS, H, HD), BF16), ((rows, PAGE, H * HD), BF16),
-        ((rows, PAGE, H * HD), BF16), ((SLOTS, MAX_SEQ // PAGE), I32),
-        ((SLOTS,), I32))
+            q, k, v, table, page_size=PAGE, length=length)
+    ).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f"[{slots},{maxp * PAGE},{hk},{hd}]" not in text
 
 
 @pytest.mark.parametrize("seq", [256, MAX_SEQ])
