@@ -1,0 +1,16 @@
+"""The ``moe`` scope's share of its roofline inside the decode segment (%):
+the larger of its least HBM bytes over the peak bandwidth and its FLOPs
+over the bf16 peak (``mla_counts.moe_decode``: the router and the shared
+experts once an iteration, each held expert a token chose, from the
+engine's ``serve.moe_*`` counters), divided by the scope's device time in
+the traced window."""
+
+
+def read(r):
+    t = r.get("device.scope._decode_segment.moe")
+    b, f = r.get("traced.moe_bytes"), r.get("traced.moe_flops")
+    if not t or not b or not f:
+        return None
+    least = max(b / r["peak.hbm_bytes_per_s"],
+                f / r["peak.bf16_flops_per_s"])
+    return 100.0 * least / t

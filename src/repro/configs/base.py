@@ -11,9 +11,22 @@ from __future__ import annotations
 import dataclasses
 import importlib
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
-__all__ = ["ModelConfig", "get_config", "list_archs", "ARCH_IDS"]
+__all__ = ["ModelConfig", "Yarn", "get_config", "list_archs", "ARCH_IDS"]
+
+
+class Yarn(NamedTuple):
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 states it:
+    the frequencies of pairs that turn fewer than ``beta_slow`` times over
+    ``original_max_pos`` positions are divided by ``factor``, those that
+    turn more than ``beta_fast`` times are kept, a linear ramp between."""
+    factor: float
+    original_max_pos: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +47,7 @@ class ModelConfig:
     sliding_window: Optional[int] = None
     layer_pattern: tuple[str, ...] = ("attn",)   # repeating block kinds
     attn_logit_softcap: Optional[float] = None
+    rope_yarn: Optional[Yarn] = None    # None → plain RoPE
 
     # MoE
     num_experts: int = 0
@@ -42,7 +56,20 @@ class ModelConfig:
     moe_d_ff: int = 0               # routed-expert hidden dim
     first_k_dense: int = 0          # leading dense-FFN layers (deepseek-v2: 1)
     moe_period: int = 1             # MoE every Nth layer (jamba: 2)
-    capacity_factor: float = 1.25
+    # routing: softmax over all ``num_experts`` router outputs; with
+    # n_group > 1 the experts form n_group groups, each scored by its best
+    # member, and the top-k experts are taken among the topk_group best
+    # groups (deepseek-v2 "group_limited_greedy"); the k weights are
+    # renormalised to sum to 1 or else scaled by routed_scaling_factor
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # the expert share this device holds: experts [first_expert,
+    # first_expert + experts_held) of the num_experts the router scores
+    # (0 → all of them); under an EP shard_map each shard holds its slice
+    experts_held: int = 0
+    first_expert: int = 0
 
     # MLA (deepseek-v2)
     use_mla: bool = False
@@ -110,6 +137,11 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.experts_held or self.num_experts
 
     @property
     def attention_free(self) -> bool:
@@ -198,14 +230,14 @@ class ModelConfig:
                 total += self._mlp_params(self.moe_d_ff if moe_here
                                           else self.d_ff) if not moe_here else 0
             if moe_here:
-                e = self.experts_per_tok if active_only else self.num_experts
+                e = self.experts_per_tok if active_only else self.held_experts
                 total += e * self._mlp_params(self.moe_d_ff)
                 total += self.num_shared_experts * self._mlp_params(self.moe_d_ff)
                 total += d * self.num_experts
             return total
         total += self._attn_params()
         if moe_here:
-            e = self.experts_per_tok if active_only else self.num_experts
+            e = self.experts_per_tok if active_only else self.held_experts
             total += e * self._mlp_params(self.moe_d_ff)
             total += self.num_shared_experts * self._mlp_params(self.moe_d_ff)
             total += d * self.num_experts  # router
@@ -218,6 +250,7 @@ class ModelConfig:
         """Tiny same-family config for CPU smoke tests."""
         period = self.pattern_period
         n_layers = max(period, 2 if period == 1 else period)
+        grouped = self.n_group > 1
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",
@@ -228,10 +261,15 @@ class ModelConfig:
             head_dim=16,
             d_ff=128,
             vocab_size=256,
-            num_experts=min(self.num_experts, 4),
+            # grouped routing keeps 4 groups of 2 (2 kept) and a share of
+            # 5 experts from the second: every mechanism, at toy sizes
+            num_experts=8 if grouped else min(self.num_experts, 4),
             experts_per_tok=min(self.experts_per_tok, 2),
+            n_group=4 if grouped else 1,
+            topk_group=2 if grouped else 1,
+            experts_held=5 if grouped else 0,
+            first_expert=1 if grouped else 0,
             moe_d_ff=64 if self.is_moe else 0,
-            capacity_factor=1e9,   # dropless routing for exactness tests
             kv_lora_rank=32 if self.use_mla else 0,
             q_lora_rank=32 if self.q_lora_rank else 0,
             rope_head_dim=8 if self.use_mla else 64,

@@ -1,4 +1,4 @@
-"""Block-sparse × dense GEMM (paper §III-C) and grouped matmul, TPU-native.
+"""Block-sparse × dense GEMM (paper §III-C), TPU-native.
 
 The paper stores A in BCSC and iterates block rows with CPU-core work queues.
 On TPU the grid must be shape-static and output-stationary, so we adapt
@@ -17,10 +17,6 @@ On TPU the grid must be shape-static and output-stationary, so we adapt
 
 Every block row must have ≥1 work item (the ops wrapper pads empty rows with
 an all-zero dummy block) so that every C tile gets written.
-
-``grouped_matmul`` reuses the identical scalar-prefetch machinery for MoE
-expert computation (one expert id per row tile of the token matrix) — the
-megablox pattern.
 """
 from __future__ import annotations
 
@@ -33,7 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-__all__ = ["block_spmm_pallas", "grouped_matmul_pallas", "densify_to_bcsr"]
+__all__ = ["block_spmm_pallas", "densify_to_bcsr"]
 
 
 def densify_to_bcsr(a_dense, bm: int, bk: int, *, pad_empty_rows: bool = True):
@@ -132,51 +128,3 @@ def block_spmm_pallas(
     )
     return fn(row_id, col_id, blocks, b)
 
-
-def grouped_matmul_pallas(
-    x,
-    group_id,
-    w,
-    *,
-    bf: int = 128,
-    out_dtype=None,
-    interpret: bool = False,
-):
-    """Per-tile expert matmul (MoE): x (T, d) in bm-row tiles, ``group_id``
-    (T//bm,) expert of each tile, w (E, d, f) → out (T, f).
-
-    The whole ``d`` dim is kept in one VMEM block (document: d·bf·dtype must
-    fit the VMEM budget — true for all assigned configs)."""
-    t_rows, d = x.shape
-    n_tiles = group_id.shape[0]
-    bm = t_rows // n_tiles
-    e, d2, f = w.shape
-    assert d2 == d and f % bf == 0
-    out_dtype = out_dtype or x.dtype
-
-    def kernel(gid_ref, x_ref, w_ref, o_ref):
-        o_ref[...] = jax.lax.dot_general(
-            x_ref[...], w_ref[0],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(o_ref.dtype)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_tiles, f // bf),
-        in_specs=[
-            pl.BlockSpec((bm, d), lambda t, j, gid_ref: (t, 0)),
-            pl.BlockSpec((1, d, bf), lambda t, j, gid_ref: (gid_ref[t], 0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bf), lambda t, j, gid_ref: (t, j)),
-    )
-    fn = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t_rows, f), out_dtype),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-    )
-    return fn(group_id, x, w)

@@ -187,14 +187,14 @@ def block_spmm(blocks, row_id, col_id, b, *, nrows_b, bn=128,
 
 
 @scoped("ops.grouped_matmul")
-def grouped_matmul(x, group_id, w, *, bf=128, out_dtype=None, backend=None):
-    backend = backend or current_backend()
-    if backend == "xla":
-        return _ref.grouped_matmul_ref(x, group_id, w, out_dtype=out_dtype)
-    from repro.kernels.block_spmm import grouped_matmul_pallas
-    return grouped_matmul_pallas(
-        x, group_id, w, bf=bf, out_dtype=out_dtype, interpret=_interp(backend),
-    )
+def grouped_matmul(x, w, group_sizes, *, out_dtype=None):
+    """Rows sorted by group times each group's matrix: x (M, K), w (G, K, N),
+    ``group_sizes`` (G,) int32 → (M, N), row block g through ``w[g]``;
+    rows past ``sum(group_sizes)`` belong to no group and come out zero.
+    One path whatever the backend: ``lax.ragged_dot``, which XLA lowers
+    natively on a TPU (the MoE layer's dropless expert product)."""
+    return jax.lax.ragged_dot(x, w, group_sizes,
+                              preferred_element_type=out_dtype)
 
 
 @scoped("ops.conv2d")

@@ -12,7 +12,7 @@ import numpy as np
 
 __all__ = [
     "matmul_ref", "brgemm_blocked_ref", "mlp_ref",
-    "block_spmm_ref", "grouped_matmul_ref", "bcsr_to_dense",
+    "block_spmm_ref", "bcsr_to_dense",
     "attention_ref", "decode_attention_ref",
     "mamba_scan_ref", "conv2d_ref",
 ]
@@ -56,7 +56,7 @@ def mlp_ref(x, weights, biases, *, activation="gelu", out_dtype=None):
 
 
 # --------------------------------------------------------------------------
-# Block-sparse × dense (paper §III-C) and grouped matmul (MoE)
+# Block-sparse × dense (paper §III-C)
 # --------------------------------------------------------------------------
 
 def bcsr_to_dense(blocks, row_id, col_id, nrows_b, ncols_b):
@@ -85,17 +85,6 @@ def block_spmm_ref(blocks, row_id, col_id, b, *, nrows_b, out_dtype=None):
     )
     out = jnp.zeros((nrows_b, bm, n), jnp.float32).at[row_id].add(partial)
     return out.reshape(nrows_b * bm, n).astype(out_dtype or b.dtype)
-
-
-def grouped_matmul_ref(x, group_id, w, *, out_dtype=None):
-    """Per-row-tile expert matmul: x (T, d) row-tiles of size bm with
-    ``group_id`` (T//bm,) expert per tile; w (E, d, f)."""
-    t_tiles = group_id.shape[0]
-    bm = x.shape[0] // t_tiles
-    xt = x.reshape(t_tiles, bm, -1)
-    wt = w[group_id]  # (T_tiles, d, f)
-    out = jnp.einsum("tbd,tdf->tbf", xt.astype(jnp.float32), wt.astype(jnp.float32))
-    return out.reshape(x.shape[0], w.shape[-1]).astype(out_dtype or x.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -183,14 +172,23 @@ def paged_decode_attention_ref(q, k_pool, v_pool, page_table, *, page_size,
 # --------------------------------------------------------------------------
 
 def attention_xla_chunked(q, k, v, *, causal=True, window=None, scale=None,
-                          block_q: int = 256, out_dtype=None):
+                          block_q: int = 256, block_kv: int = 4096,
+                          out_dtype=None):
     """Memory-bounded attention for the XLA path: scan over query blocks with
     a checkpointed body, so only one (B, Hk, g, bq, Skv) score block is ever
     live and the backward recomputes it (the flash-attention memory property,
     expressed in pure lax — this is what the dry-run lowers; the Pallas flash
     kernel is the TPU runtime fast path).  GQA handled by grouping query
     heads — no kv ``repeat`` (keeps kv-head sharding propagation intact and
-    avoids the g× copy)."""
+    avoids the g× copy).
+
+    Keys past ``block_kv`` are taken in blocks of ``block_kv`` with an
+    online softmax, so no row of scores is longer: XLA's TPU compiler turns
+    a row's max into a reduce-window as wide as the row, and at 8192 keys
+    its cost model overflows and picks a tiling ≈ 30× slower.  Rows of up
+    to ``block_kv`` keys keep the plain softmax: the online form over one
+    block made a minicpm-2b (7-layer) train step 6.6% slower on a TPU v5e
+    (15,329 against 16,403 tokens/s)."""
     b, h, sq, d = q.shape
     hk, skv = k.shape[1], k.shape[2]
     vd = v.shape[-1]  # may differ from d (MLA: q/k carry rope dims, v not)
@@ -204,24 +202,48 @@ def attention_xla_chunked(q, k, v, *, causal=True, window=None, scale=None,
     qg = q.reshape(b, hk, g, sq, d)
     cols = jnp.arange(skv)[None, :]
 
+    def scores(qb, i, kc, cc):
+        s = jnp.einsum("bhgqd,bhkd->bhgqk", qb, kc,
+                       preferred_element_type=jnp.float32) * scale
+        rows = (i * bq + off) + jnp.arange(bq)[:, None]
+        mask = jnp.ones((bq, kc.shape[2]), bool)
+        if causal:
+            mask = mask & (cc <= rows)
+        if window is not None:
+            mask = mask & (cc > rows - window)
+        return s, mask
+
     @jax.checkpoint
     def body(carry, _):
         i, = carry
         qb = jax.lax.dynamic_slice(qg, (0, 0, 0, i * bq, 0),
                                    (b, hk, g, bq, d))
-        s = jnp.einsum("bhgqd,bhkd->bhgqk", qb, k,
-                       preferred_element_type=jnp.float32) * scale
-        rows = (i * bq + off) + jnp.arange(bq)[:, None]
-        mask = jnp.ones((bq, skv), bool)
-        if causal:
-            mask = mask & (cols <= rows)
-        if window is not None:
-            mask = mask & (cols > rows - window)
-        s = jnp.where(mask, s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        ob = jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v.dtype), v,
-                        preferred_element_type=jnp.float32)
-        return (i + 1,), ob.astype(out_dtype or q.dtype)
+        if skv <= block_kv:
+            s, mask = scores(qb, i, k, cols)
+            p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+            ob = jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v.dtype), v,
+                            preferred_element_type=jnp.float32)
+            return (i + 1,), ob.astype(out_dtype or q.dtype)
+        # a finite floor: a block wholly masked for a row weighs nothing
+        # once a later block holds the row's true max
+        m = l = acc = None
+        for c0 in range(0, skv, block_kv):
+            c1 = min(c0 + block_kv, skv)
+            s, mask = scores(qb, i, k[:, :, c0:c1], cols[:, c0:c1])
+            s = jnp.where(mask, s, -1e30)
+            m_new = s.max(-1, keepdims=True)
+            if m is not None:
+                m_new = jnp.maximum(m, m_new)
+            p = jnp.exp(s - m_new)
+            pv = jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v.dtype),
+                            v[:, :, c0:c1], preferred_element_type=jnp.float32)
+            if m is None:
+                l, acc = p.sum(-1, keepdims=True), pv
+            else:
+                a = jnp.exp(m - m_new)
+                l, acc = a * l + p.sum(-1, keepdims=True), a * acc + pv
+            m = m_new
+        return (i + 1,), (acc / l).astype(out_dtype or q.dtype)
 
     _, blocks = jax.lax.scan(body, (jnp.zeros((), jnp.int32),), None,
                              length=nblk)

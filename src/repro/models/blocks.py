@@ -63,19 +63,62 @@ def init_norm(cfg: ModelConfig, key):
 # RoPE (full / partial-fraction "2D" variants)
 # --------------------------------------------------------------------------
 
-def apply_rope(x, positions, *, theta: float, fraction: float = 1.0):
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention temperature factor, ``0.1·m·ln(s) + 1`` (1 for
+    s ≤ 1)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_correction_range(yarn, rot: int, theta: float) -> tuple[int, int]:
+    """The pair indices between which YaRN ramps from interpolated to
+    original frequencies: those that turn ``beta_fast`` and ``beta_slow``
+    times over the original context, floored / ceiled and clipped."""
+    def dim(turns):
+        return (rot * math.log(yarn.original_max_pos / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(dim(yarn.beta_fast)), 0),
+            min(math.ceil(dim(yarn.beta_slow)), rot - 1))
+
+
+def rope_inv_freq(rot: int, theta: float, yarn=None) -> np.ndarray:
+    """Inverse frequencies of the ``rot // 2`` rotated pairs (float32);
+    with ``yarn`` (``configs.base.Yarn``), DeepSeek-V2's blend of the
+    original frequencies and those divided by ``yarn.factor``."""
+    half = rot // 2
+    extra = theta ** (-np.arange(half, dtype=np.float32) / half)
+    if yarn is None:
+        return extra
+    low, high = yarn_correction_range(yarn, rot, theta)
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp                       # 1 → original frequency
+    return (extra / yarn.factor * (1.0 - keep) + extra * keep).astype(
+        np.float32)
+
+
+def apply_rope(x, positions, *, theta: float, fraction: float = 1.0,
+               yarn=None):
     """x (B, S, H, D); positions (B, S).  Rotates the first
     ``even(D*fraction)`` dims (chatglm/glm4 half-dim RoPE = fraction 0.5,
-    gptj = 0.25), passes the rest through."""
+    gptj = 0.25), passes the rest through.  ``yarn`` swaps in YaRN's
+    frequencies and scales cos / sin by mscale ÷ mscale_all_dim."""
     d = x.shape[-1]
     rot = int(d * fraction) // 2 * 2
     if rot == 0:
         return x
     xr, xp = x[..., :rot], x[..., rot:]
     half = rot // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(rope_inv_freq(rot, theta, yarn))
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B,S,half)
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = xr[..., :half].astype(jnp.float32), xr[..., half:].astype(jnp.float32)
     xr = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return jnp.concatenate([xr.astype(x.dtype), xp], axis=-1)
@@ -312,6 +355,17 @@ def init_mla(cfg: ModelConfig, key):
     }
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(qk_nope + qk_rope)^-½, times YaRN's mscale(factor, mscale_all_dim)²
+    where the model states one (DeepSeek-V2: × 1.2608² = 1.5896)."""
+    scale = 1.0 / math.sqrt(cfg.head_dim + cfg.rope_head_dim)
+    y = cfg.rope_yarn
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+@ops.scoped("mla")
 def mla_apply(cfg: ModelConfig, p, x, *, positions=None, cache=None,
               cache_pos=None, page_table=None, page_size: int = 0):
     """Multi-head Latent Attention.  The KV cache stores only the compressed
@@ -337,15 +391,17 @@ def mla_apply(cfg: ModelConfig, p, x, *, positions=None, cache=None,
     q = ops.matmul(q_lat, pw["wq_b"]).reshape(b, s, h, hd + rd)
     q = constrain(q, ("batch", None, "heads", None))
     q_nope, q_rope = q[..., :hd], q[..., hd:]
-    q_rope = apply_rope(q_rope, positions, theta=cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, theta=cfg.rope_theta,
+                        yarn=cfg.rope_yarn)
 
     kv = ops.matmul(x.reshape(b * s, d), pw["wkv_a"]).reshape(b, s, kvr + rd)
     c_kv, k_rope = kv[..., :kvr], kv[..., kvr:]
     c_kv = tpp.rmsnorm(c_kv, pw["kv_norm"])
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, theta=cfg.rope_theta)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, theta=cfg.rope_theta,
+                        yarn=cfg.rope_yarn)
 
     latent = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)  # (B,S,kvr+rd)
-    scale = 1.0 / math.sqrt(hd + rd)
+    scale = mla_softmax_scale(cfg)
 
     new_cache = None
     paged = page_table is not None
@@ -472,12 +528,14 @@ def mlp_apply(cfg: ModelConfig, p, x2d):
 
 
 def init_moe(cfg: ModelConfig, key):
-    d, e, ff = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    """The router scores all ``num_experts``; the expert weights are the
+    ``held_experts`` this device holds, stacked (E_held, fan-in, fan-out)."""
+    d, e, ff = cfg.d_model, cfg.held_experts, cfg.moe_d_ff
     ks = jax.random.split(key, 5)
     p = {
-        "router": _init(ks[0], (d, e), scale=0.02),
-        "wg": _init(ks[1], (e, d, ff)),
-        "wu": _init(ks[2], (e, d, ff)),
+        "router": _init(ks[0], (d, cfg.num_experts), scale=0.02),
+        "wg": _init(ks[1], (e, d, ff), scale=1.0 / math.sqrt(d)),
+        "wu": _init(ks[2], (e, d, ff), scale=1.0 / math.sqrt(d)),
         "wd": _init(ks[3], (e, ff, d), scale=1.0 / math.sqrt(ff)),
     }
     if cfg.num_shared_experts:
@@ -485,89 +543,83 @@ def init_moe(cfg: ModelConfig, key):
     return p
 
 
-def _expert_ffn(cfg, wg, wu, wd, xe):
-    """xe (E_loc, C, d) → (E_loc, C, d): batched gated FFN over local experts.
+def moe_route(cfg: ModelConfig, logits):
+    """Router logits (T, num_experts) → (softmax scores (T, E), weights
+    (T, k), expert ids (T, k)), all in float32.  With ``n_group > 1`` the
+    top k are taken among the members of the ``topk_group`` groups whose
+    best score is highest (DeepSeek-V2's group-limited greedy); weights are
+    renormalised (``norm_topk_prob``) or else × ``routed_scaling_factor``."""
+    t, e = logits.shape
+    k = cfg.experts_per_tok
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    scores = probs
+    if cfg.n_group > 1:
+        per = e // cfg.n_group
+        best = probs.reshape(t, cfg.n_group, per).max(-1)
+        _, keep = jax.lax.top_k(best, cfg.topk_group)
+        kept = jnp.any(keep[..., None] == jnp.arange(cfg.n_group), axis=1)
+        scores = jnp.where(jnp.repeat(kept, per, axis=1), probs, 0.0)
+    topw, topi = jax.lax.top_k(scores, k)
+    if cfg.norm_topk_prob:
+        topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    else:
+        topw = topw * cfg.routed_scaling_factor
+    return probs, topw, topi
 
-    With ``cfg.use_fusion`` each expert's gated up-projection runs through the
-    two-root ``fused_gated_mlp_graph`` (per-expert 2D GEMMs; E_loc is a small
-    static count, so the unrolled loop stays cheap and every expert reuses
-    the same memoized compiled graph)."""
-    if cfg.use_fusion:
-        from repro.fusion import fused_gated_mlp_apply
-        h = jnp.stack([
-            fused_gated_mlp_apply(xe[e], wg[e], wu[e],
-                                  activation=cfg.mlp_activation)
-            for e in range(xe.shape[0])
-        ]).astype(xe.dtype)
-        return jnp.einsum("ecf,efd->ecd", h, wd,
-                          preferred_element_type=jnp.float32).astype(xe.dtype)
-    act = tpp.UNARY_TPPS[cfg.mlp_activation]
-    g = jnp.einsum("ecd,edf->ecf", xe, wg, preferred_element_type=jnp.float32)
-    u = jnp.einsum("ecd,edf->ecf", xe, wu, preferred_element_type=jnp.float32)
-    h = (act(g) * u).astype(xe.dtype)
-    return jnp.einsum("ecf,efd->ecd", h, wd, preferred_element_type=jnp.float32
-                      ).astype(xe.dtype)
 
+@ops.scoped("moe")
+def moe_apply(cfg: ModelConfig, p, x2d, *, ep_axis: Optional[str] = None,
+              token_mask=None):
+    """Token-choice top-k MoE over the held experts, dropless: (T, d) →
+    ((T, d), aux).
 
-def moe_apply(cfg: ModelConfig, p, x2d, *, ep_axis: Optional[str] = None):
-    """Token-choice top-k MoE with capacity-bounded dispatch (T, d) → (T, d).
+    The router scores every expert (``moe_route``); this device computes,
+    for every token, the weighted SiLU-gated FFN of each selected expert
+    that it holds, ``[first, first + held)``, and adds the shared experts
+    once.  Selections of experts held elsewhere add nothing here: their
+    part of the result is another device's.  The held part is one grouped
+    product (``ops.grouped_matmul``) over the T·k (token, choice) rows
+    sorted by held expert, rows of other experts last — fixed shapes, no
+    capacity, no token dropped.
 
-    Expert parallelism: when ``ep_axis`` is set (inside shard_map), tokens are
-    replicated over the axis, expert weights sharded over it; each shard
-    gathers its local experts' tokens, runs the batched FFN, scatters back and
-    psums the partial outputs — EP-as-TP, deterministic fixed-shape
-    collectives for the dry-run (DESIGN.md §5).
-    """
+    Under an EP ``shard_map`` (``ep_axis``) the held experts are the
+    shard's slice of the device's share, and the partial outputs psum.
+
+    ``aux``: ``loss`` (Switch-style load balance over all experts) and the
+    counts over the tokens ``token_mask`` ((T,) bool; all by default)
+    keeps: ``moe_assignments`` (token-expert selections that land on held
+    experts) and ``moe_experts_touched`` (held experts with a token)."""
     dt = compute_dtype(cfg)
     t, d = x2d.shape
-    e, k = cfg.num_experts, cfg.experts_per_tok
+    k = cfg.experts_per_tok
     pw = _cast(p, dt)
+    held = pw["wg"].shape[0]
 
-    logits = ops.matmul(x2d, pw["router"], out_dtype=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    topw, topi = jax.lax.top_k(probs, k)                    # (T, k)
-    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe.route"):
+        logits = ops.matmul(x2d, pw["router"], out_dtype=jnp.float32)
+        probs, topw, topi = moe_route(cfg, logits)
+        first = cfg.first_expert
+        if ep_axis is not None:
+            first = first + jax.lax.axis_index(ep_axis) * held
+        local = (topi - first).reshape(-1)                  # (T·k,)
+        mine = (local >= 0) & (local < held)
+        group = jnp.where(mine, local, held)                # held → last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
 
-    if ep_axis is not None:
-        w = jax.lax.psum(1, ep_axis)
-        shard = jax.lax.axis_index(ep_axis)
-        e_loc = e // w
-    else:
-        w, shard, e_loc = 1, 0, e
-
-    # per-expert capacity; a token contributes at most once per expert, so
-    # t is the dropless upper bound (reduced test configs set a huge
-    # capacity_factor to make routing exactly dropless)
-    cap = int(min(t, max(1, math.ceil(cfg.capacity_factor * t * k / e))))
-
-    # slot ranking within each expert (capacity-drop beyond `cap`)
-    flat_e = topi.reshape(-1)                               # (T*k,)
-    sort_idx = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[sort_idx]
-    first_occ = jnp.searchsorted(sorted_e, sorted_e, side="left")
-    rank_sorted = jnp.arange(t * k) - first_occ
-    rank = jnp.zeros((t * k,), jnp.int32).at[sort_idx].set(rank_sorted.astype(jnp.int32))
-
-    local_e = flat_e - shard * e_loc
-    in_shard = (local_e >= 0) & (local_e < e_loc) & (rank < cap)
-    slot = jnp.where(in_shard, local_e * cap + rank, e_loc * cap)  # OOB → drop
-
-    xe = jnp.zeros((e_loc * cap + 1, d), dt)
-    token_of = jnp.repeat(jnp.arange(t), k)
-    xe = xe.at[slot].set(x2d[token_of], mode="drop")
-    xe = xe[: e_loc * cap].reshape(e_loc, cap, d)
-
-    ye = _expert_ffn(cfg, pw["wg"], pw["wu"], pw["wd"], xe)
-
-    ye_flat = jnp.concatenate([ye.reshape(e_loc * cap, d),
-                               jnp.zeros((1, d), dt)], axis=0)
-    contrib = ye_flat[slot] * topw.reshape(-1)[:, None].astype(dt)
-    contrib = jnp.where(in_shard[:, None], contrib, 0)
-    # combine without a scatter: slot order is (token, k)-major, so the
-    # per-token sum is a reshape + k-reduction (fp32 accumulate) — avoids
-    # XLA materializing (T·k, d) fp32 buffers + u32 index arrays
-    y = jnp.einsum("tkd->td", contrib.reshape(t, k, d),
-                   preferred_element_type=jnp.float32)
+    # rows sorted by held expert; rows of other experts trail the groups
+    rows = x2d[order // k]
+    g = ops.grouped_matmul(rows, pw["wg"], sizes, out_dtype=jnp.float32)
+    u = ops.grouped_matmul(rows, pw["wu"], sizes, out_dtype=jnp.float32)
+    h = (tpp.UNARY_TPPS[cfg.mlp_activation](g) * u).astype(dt)
+    ys = ops.grouped_matmul(h, pw["wd"], sizes, out_dtype=jnp.float32)
+    # rows of other experts are dropped by `where`, not by a zero weight:
+    # what the product leaves in them is not promised
+    ys = jnp.where(mine[order][:, None], ys * topw.reshape(-1)[order][:, None],
+                   0.0)
+    # back to (token, choice) order, so the per-token sum is a k-reduction
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+    y = ys[back].reshape(t, k, d).sum(1)
     if ep_axis is not None:
         y = jax.lax.psum(y, ep_axis)
     y = y.astype(dt)
@@ -575,7 +627,17 @@ def moe_apply(cfg: ModelConfig, p, x2d, *, ep_axis: Optional[str] = None):
     if cfg.num_shared_experts:
         y = y + mlp_apply(cfg, p["shared"], x2d)
 
-    aux = _moe_aux_loss(probs, topi, e)
+    keep = (jnp.ones((t,), bool) if token_mask is None
+            else jnp.asarray(token_mask, bool))
+    counted = mine & jnp.repeat(keep, k)
+    per_expert = jnp.zeros((held,), jnp.int32).at[
+        jnp.where(counted, local, held)].add(1, mode="drop")
+    aux = {"loss": _moe_aux_loss(probs, topi, cfg.num_experts),
+           "moe_assignments": jnp.sum(counted, dtype=jnp.int32),
+           "moe_experts_touched": jnp.sum(per_expert > 0, dtype=jnp.int32)}
+    if ep_axis is not None:
+        for name in ("moe_assignments", "moe_experts_touched"):
+            aux[name] = jax.lax.psum(aux[name], ep_axis)
     return y, aux
 
 

@@ -83,19 +83,37 @@ def init_block(cfg: ModelConfig, key, kind: str, moe: bool, cross: bool = False)
     return p
 
 
+def aux_zero(cfg: ModelConfig) -> dict:
+    """What a block adds up besides its output: the MoE load-balance loss,
+    and for an MoE model the expert layer's counts (``blocks.moe_apply``).
+    The keys depend on the config alone, so a dense model's programs carry
+    the loss and nothing more."""
+    aux = {"loss": jnp.zeros((), jnp.float32)}
+    if cfg.is_moe:
+        aux["moe_assignments"] = jnp.zeros((), jnp.int32)
+        aux["moe_experts_touched"] = jnp.zeros((), jnp.int32)
+    return aux
+
+
+def _add(a, b):
+    return jax.tree.map(jnp.add, a, b)
+
+
 def block_apply(cfg: ModelConfig, p, x, *, kind: str, moe: bool,
                 cache=None, cache_pos=0, positions=None, xattn_kv=None,
                 ep_axis: Optional[str] = None, dropout_seed=None,
-                page_table=None, page_size: int = 0, seq_lengths=None):
+                page_table=None, page_size: int = 0, seq_lengths=None,
+                token_mask=None):
     """Pre-norm residual block.  ``dropout_seed`` (train only, already
     folded per layer) enables the attention-output dropout at
     ``cfg.dropout_rate``.  ``cache_pos`` may be a per-slot ``(B,)`` vector
     and ``page_table``/``page_size`` switch the attention caches to the
     paged pool layout (see ``blocks.attention_apply``); mamba state stays
     per-slot but honours ``seq_lengths`` ((B,) valid-token counts) so
-    bucket-padded prefill leaves exact SSM state.
-    Returns (x, new_cache, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+    bucket-padded prefill leaves exact SSM state.  ``token_mask`` ((B,)
+    bool) says which sequences the expert layer's counts count.
+    Returns (x, new_cache, aux) — ``aux`` as ``aux_zero`` gives it."""
+    aux = aux_zero(cfg)
     h = B._norm(cfg, p["norm1"], x)
     new_cache = dict(cache) if cache is not None else None
     res_folded = False
@@ -142,7 +160,9 @@ def block_apply(cfg: ModelConfig, p, x, *, kind: str, moe: bool,
         b, s, d = h.shape
         h2 = h.reshape(b * s, d)
         if moe:
-            y, aux = _moe_maybe_sharded(cfg, p["moe"], h2, ep_axis)
+            mask = (None if token_mask is None
+                    else jnp.repeat(jnp.asarray(token_mask, bool), s))
+            y, aux = _moe_maybe_sharded(cfg, p["moe"], h2, ep_axis, mask)
         else:
             y = B.mlp_apply(cfg, p["mlp"], h2)
         x = x + y.reshape(b, s, d)
@@ -150,12 +170,12 @@ def block_apply(cfg: ModelConfig, p, x, *, kind: str, moe: bool,
     return x, new_cache, aux
 
 
-def _moe_maybe_sharded(cfg: ModelConfig, p, x2d, ep_axis):
+def _moe_maybe_sharded(cfg: ModelConfig, p, x2d, ep_axis, token_mask=None):
     """Run the MoE layer under shard_map (EP over ``ep_axis``) when a mesh
     rule set is active; plain single-device execution otherwise."""
     rules = active_rules()
     if ep_axis is None or rules is None or ep_axis not in rules.mesh.shape:
-        return B.moe_apply(cfg, p, x2d, ep_axis=None)
+        return B.moe_apply(cfg, p, x2d, ep_axis=None, token_mask=token_mask)
     from jax.sharding import PartitionSpec as P
 
     mesh = rules.mesh
@@ -178,14 +198,16 @@ def _moe_maybe_sharded(cfg: ModelConfig, p, x2d, ep_axis):
     if "shared" in p:
         wspec["shared"] = jax.tree.map(lambda _: P(), p["shared"])
 
+    if token_mask is None:
+        token_mask = jnp.ones((x2d.shape[0],), bool)
     fn = jax.shard_map(
-        partial(B.moe_apply, cfg, ep_axis=ep_axis),
+        lambda p, x, m: B.moe_apply(cfg, p, x, ep_axis=ep_axis, token_mask=m),
         mesh=mesh,
-        in_specs=(wspec, tok_spec),
+        in_specs=(wspec, tok_spec, P(tok_spec[0])),
         out_specs=(tok_spec, P()),
         check_vma=False,
     )
-    return fn(p, x2d)
+    return fn(p, x2d, token_mask)
 
 
 # --------------------------------------------------------------------------
@@ -235,7 +257,8 @@ def init_params(cfg: ModelConfig, key):
 def _apply_groups(cfg, gparams_list, groups, x, *, caches=None, cache_pos=0,
                   positions=None, xattn_kv=None, ep_axis=None, remat=True,
                   cross=False, unroll=False, dropout_seed=None,
-                  page_table=None, page_size=0, seq_lengths=None):
+                  page_table=None, page_size=0, seq_lengths=None,
+                  token_mask=None):
     """Scan each group over its repeat axis; thread caches and aux loss.
 
     ``unroll=True`` replaces the depth scan with a trace-time loop — used by
@@ -246,14 +269,14 @@ def _apply_groups(cfg, gparams_list, groups, x, *, caches=None, cache_pos=0,
     layer index (``fusion.rng.fold_in``) so every layer draws an independent
     dropout stream from one seed — identical across fused/unfused paths and
     across scan/unroll layouts."""
-    total_aux = jnp.zeros((), jnp.float32)
+    total_aux = aux_zero(cfg)
     new_caches = []
     layer_base = 0
     for gi, (gparams, group) in enumerate(zip(gparams_list, groups)):
         gcache = caches[gi] if caches is not None else None
 
         def period(x, pparams, pcache, lidx0):
-            aux_p = jnp.zeros((), jnp.float32)
+            aux_p = aux_zero(cfg)
             ncache = [] if pcache is not None else None
             for pos_i, (kind, moe) in enumerate(group.kinds):
                 if dropout_seed is not None:
@@ -265,7 +288,8 @@ def _apply_groups(cfg, gparams_list, groups, x, *, caches=None, cache_pos=0,
                              cache_pos=cache_pos, positions=positions,
                              xattn_kv=xattn_kv, ep_axis=ep_axis,
                              dropout_seed=seed_i, page_table=page_table,
-                             page_size=page_size, seq_lengths=seq_lengths)
+                             page_size=page_size, seq_lengths=seq_lengths,
+                             token_mask=token_mask)
                 if remat:
                     fn = jax.checkpoint(
                         fn, policy=jax.checkpoint_policies.nothing_saveable,
@@ -278,7 +302,7 @@ def _apply_groups(cfg, gparams_list, groups, x, *, caches=None, cache_pos=0,
                 )
                 if ncache is not None:
                     ncache.append(c)
-                aux_p = aux_p + aux
+                aux_p = _add(aux_p, aux)
             return x, ncache, aux_p
 
         period_len = len(group.kinds)
@@ -290,7 +314,7 @@ def _apply_groups(cfg, gparams_list, groups, x, *, caches=None, cache_pos=0,
                           if gcache is not None else None)
                 x, ncache, aux_p = period(
                     x, pparams, pcache, layer_base + r * period_len)
-                total_aux = total_aux + aux_p
+                total_aux = _add(total_aux, aux_p)
                 if ncache is not None:
                     ncaches_list.append(ncache)
             new_caches.append(
@@ -301,7 +325,7 @@ def _apply_groups(cfg, gparams_list, groups, x, *, caches=None, cache_pos=0,
                 x, aux_acc = carry
                 pparams, pcache, lidx0 = xs
                 x, ncache, aux_p = period(x, pparams, pcache, lidx0)
-                return (x, aux_acc + aux_p), ncache
+                return (x, _add(aux_acc, aux_p)), ncache
 
             lidx = layer_base + jnp.arange(group.repeat) * period_len
             xs = (gparams, gcache, lidx)
@@ -326,8 +350,9 @@ def _positions_from(pos0, b, s):
 def forward_hidden(cfg: ModelConfig, params, batch, *, caches=None,
                    cache_pos=0, ep_axis=None, remat=True, unroll=False,
                    dropout_seed=None, page_table=None, page_size=0,
-                   seq_lengths=None):
-    """→ (hidden (B, S, d) fp-compute, new_caches, aux).  ``batch`` keys:
+                   seq_lengths=None, token_mask=None):
+    """→ (hidden (B, S, d) fp-compute, new_caches, aux) — ``aux`` the sum
+    over layers of what ``block_apply`` adds up (``aux_zero``).  ``batch`` keys:
     tokens (B,S) [+ patches (B,P,d) for vlm; frames (B,F,d) for encdec].
     ``dropout_seed`` (train only) enables ``cfg.dropout_rate`` dropout in
     the decoder blocks — per-layer streams are folded in downstream.
@@ -363,7 +388,7 @@ def forward_hidden(cfg: ModelConfig, params, batch, *, caches=None,
         cache_pos=cache_pos, positions=positions, xattn_kv=xattn_kv,
         ep_axis=ep_axis, remat=remat, unroll=unroll,
         dropout_seed=dropout_seed, page_table=page_table,
-        page_size=page_size, seq_lengths=seq_lengths)
+        page_size=page_size, seq_lengths=seq_lengths, token_mask=token_mask)
     x = B._norm(cfg, params["final_norm"], x)
     new_caches = None
     if caches is not None:
@@ -453,8 +478,8 @@ def lm_loss(cfg: ModelConfig, params, batch, *, ep_axis=None, remat=True,
         (tot, cnt), _ = jax.lax.scan(body, (jnp.zeros(()), jnp.zeros(())),
                                      xs)
     ce = tot / jnp.maximum(cnt, 1.0)
-    loss = ce + aux_weight * aux
-    return loss, {"ce": ce, "aux": aux, "tokens": cnt}
+    loss = ce + aux_weight * aux["loss"]
+    return loss, {"ce": ce, "aux": aux["loss"], "tokens": cnt}
 
 
 # --------------------------------------------------------------------------
@@ -575,20 +600,28 @@ def prefill(cfg: ModelConfig, params, caches, batch, *, ep_axis=None,
 
 
 def decode_step(cfg: ModelConfig, params, caches, tokens, pos, *,
-                ep_axis=None, unroll=False, page_table=None, page_size=0):
+                ep_axis=None, unroll=False, page_table=None, page_size=0,
+                token_mask=None):
     """One decode step: tokens (B,) int32, ``pos`` scalar int32 position —
     or per-slot (B,) positions for continuous batching.
-    Returns (logits (B,V), new caches)."""
+    Returns (logits (B,V), new caches); with ``token_mask`` ((B,) bool, the
+    slots whose tokens count), also the expert layer's counts summed over
+    layers (``aux_zero``'s ``moe_*`` keys; empty for a dense model)."""
     batch = {"tokens": tokens[:, None]}
-    h, caches, _ = forward_hidden(cfg, params, batch, caches=caches,
-                                  cache_pos=pos, ep_axis=ep_axis, remat=False,
-                                  unroll=unroll, page_table=page_table,
-                                  page_size=page_size)
+    h, caches, aux = forward_hidden(cfg, params, batch, caches=caches,
+                                    cache_pos=pos, ep_axis=ep_axis,
+                                    remat=False, unroll=unroll,
+                                    page_table=page_table,
+                                    page_size=page_size,
+                                    token_mask=token_mask)
     with jax.named_scope("lm_head"):
         w = _unembed_weight(cfg, params)
         logits = h[:, -1].astype(jnp.float32) @ w.astype(jnp.float32)
         logits = constrain(logits, ("batch", "vocab"))
-        return _mask_pad_logits(cfg, logits), caches
+        logits = _mask_pad_logits(cfg, logits)
+    if token_mask is None:
+        return logits, caches
+    return logits, caches, {k: v for k, v in aux.items() if k != "loss"}
 
 
 def finite_logits(logits) -> jax.Array:

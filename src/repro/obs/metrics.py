@@ -58,6 +58,8 @@ METRIC_CATALOG = {
     "serve.decode_iterations": "counter: iterations the decode segments' while_loops ran",
     "serve.decode_slot_iterations": "counter: active slots summed over those iterations",
     "serve.decode_kv_pages": "counter: KV pages the active slots' paged attention read, summed over those iterations",
+    "serve.moe_assignments": "counter: the active slots' token-expert assignments on held experts, summed over MoE layers and decode iterations",
+    "serve.moe_experts_touched": "counter: held experts with an active slot's token, summed over MoE layers and decode iterations",
     # fusion compiler (process-global registry)
     "fusion.compile_cache.hits": "counter: compile_for_backend memo hits",
     "fusion.compile_cache.misses": "counter: compile_for_backend memo misses",

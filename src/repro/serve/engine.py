@@ -221,6 +221,9 @@ class Engine:
         self._c_iters = reg.counter("serve.decode_iterations")
         self._c_slot_iters = reg.counter("serve.decode_slot_iterations")
         self._c_kv_pages = reg.counter("serve.decode_kv_pages")
+        self._c_moe = {
+            "moe_assignments": reg.counter("serve.moe_assignments"),
+            "moe_experts_touched": reg.counter("serve.moe_experts_touched")}
         self._c_submitted = reg.counter("serve.requests.submitted")
         self._term_counters = {
             RequestStatus.FINISHED: reg.counter("serve.requests.finished"),
@@ -237,11 +240,16 @@ class Engine:
         self._step_events: list[tuple[str, dict]] = []
         self._tokens_harvested = 0
         # what the decode segments' while_loops report: iterations run,
-        # active slots summed over them, and the KV pages those slots'
-        # attention reads (plain ints, like tokens_generated)
+        # active slots summed over them, the KV pages (latent pages for
+        # MLA) those slots' attention reads, and for an MoE model the
+        # active slots' expert assignments on held experts and the held
+        # experts they touch, summed over layers and iterations (plain
+        # ints, like tokens_generated)
         self.decode_iterations = 0
         self.decode_slot_iterations = 0
         self.decode_kv_pages = 0
+        self.moe_assignments = 0
+        self.moe_experts_touched = 0
 
         self._prefill, self._segment = _jitted_fns(cfg, ecfg,
                                                    ops.current_backend())
@@ -704,15 +712,15 @@ class Engine:
         with self.tracer.span("engine.decode_segment",
                               slots=len(self.sched.running)) as sp:
             (self.caches, self._state, out, iters, slot_iters,
-             kv_pages) = self._segment(
+             kv_pages, moe) = self._segment(
                 self.params, self.caches, self._state, self._table_dev,
                 self._seed, refill, self._poison_uid, self._poison_pos)
             # ONE host sync per segment: everything the host bookkeeping needs
             with self.tracer.span("engine.wait"):
                 (gen_after, still_active, bad, out, iters, slot_iters,
-                 kv_pages) = jax.device_get(
+                 kv_pages, moe) = jax.device_get(
                     (self._state.gen, self._state.active, self._state.bad,
-                     out, iters, slot_iters, kv_pages))
+                     out, iters, slot_iters, kv_pages, moe))
         with self.tracer.span("engine.harvest") as hp:
             now = self._now()
             harvested = 0
@@ -734,6 +742,9 @@ class Engine:
             self._c_iters.inc(int(iters))
             self._c_slot_iters.inc(int(slot_iters))
             self._c_kv_pages.inc(int(kv_pages))
+            for name, n in moe.items():
+                setattr(self, name, getattr(self, name) + int(n))
+                self._c_moe[name].inc(int(n))
             self._gen = gen_after.copy()
             self._done |= running & ~still_active & ~bad
         return running & bad
@@ -800,6 +811,8 @@ class Engine:
 # as the weights.  BOUND_ARGS is the (cfg, ecfg) prefix bound before jit;
 # donate_argnums are relative to the remaining parameters.
 DONATED_ARGS = ("caches", "state")
+# the expert layer's counts a decode segment returns (``lm.aux_zero``)
+MOE_COUNTERS = ("moe_assignments", "moe_experts_touched")
 BOUND_ARGS = 2
 
 
@@ -885,28 +898,31 @@ def _decode_segment(cfg, ecfg, params, caches, state, table, seed, refill,
     non-finite (organically, or via the fault plan's ``poison_*``
     injection) is flagged ``bad``, contributes no token, and stops
     advancing — the other slots keep decoding.  Also returns the
-    iterations the loop ran, the active slots summed over them, and the KV
+    iterations the loop ran, the active slots summed over them, the KV
     pages those slots' paged attention reads, ``ceil((pos + 1) /
-    page_size)`` each, summed the same way (int32 scalars)."""
+    page_size)`` each (latent pages for MLA), summed the same way (int32
+    scalars), and a dict of the expert layer's counts over the active
+    slots (``MOE_COUNTERS``; empty for a dense model)."""
     seg = ecfg.segment_len
     b = state.tok.shape[0]
     out0 = jnp.full((b, seg), -1, jnp.int32)
 
     def cond(c):
-        t, _, st, _, finished_any, _, _ = c
+        t, _, st, _, finished_any, _, _, _ = c
         return (t < seg) & jnp.any(st.active) & ~(refill & finished_any)
 
     def body(c):
-        t, caches, st, out, finished_any, slot_iters, kv_pages = c
+        t, caches, st, out, finished_any, slot_iters, kv_pages, moe = c
         slot_iters = slot_iters + jnp.sum(st.active, dtype=jnp.int32)
         kv_pages = kv_pages + jnp.sum(
             jnp.where(st.active, (st.pos + ecfg.page_size) // ecfg.page_size,
                       0), dtype=jnp.int32)
         tok_in = jnp.where(st.active, st.tok, 0)
-        logits, caches = lm.decode_step(
+        logits, caches, counts = lm.decode_step(
             cfg, params, caches, tok_in, st.pos, ep_axis=ecfg.ep_axis,
             unroll=ecfg.unroll_layers, page_table=table,
-            page_size=ecfg.page_size)
+            page_size=ecfg.page_size, token_mask=st.active)
+        moe = {k: moe[k] + counts[k] for k in moe}
         hit = st.active & (st.uids == poison_uid) & (st.pos + 1 >= poison_pos)
         logits = jnp.where(hit[:, None], jnp.float32(jnp.nan), logits)
         bad_now = st.active & ~lm.finite_logits(logits)
@@ -926,9 +942,11 @@ def _decode_segment(cfg, ecfg, params, caches, state, table, seed, refill,
             gen=gen, active=alive & ~done, bad=st.bad | bad_now)
         return (t + 1, caches, st, out,
                 finished_any | jnp.any(done) | jnp.any(bad_now), slot_iters,
-                kv_pages)
+                kv_pages, moe)
 
-    iters, caches, st, out, _, slot_iters, kv_pages = jax.lax.while_loop(
+    moe0 = {k: jnp.int32(0) for k in MOE_COUNTERS} if cfg.is_moe else {}
+    (iters, caches, st, out, _, slot_iters, kv_pages,
+     moe) = jax.lax.while_loop(
         cond, body, (jnp.int32(0), caches, state, out0, jnp.bool_(False),
-                     jnp.int32(0), jnp.int32(0)))
-    return caches, st, out, iters, slot_iters, kv_pages
+                     jnp.int32(0), jnp.int32(0), moe0))
+    return caches, st, out, iters, slot_iters, kv_pages, moe

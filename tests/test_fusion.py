@@ -278,26 +278,6 @@ def test_attention_use_fusion_flag_matches_unfused():
                                rtol=2e-2, atol=2e-1)
 
 
-def test_expert_ffn_use_fusion_matches_unfused():
-    """_expert_ffn per-expert fused gated path equals the batched einsums."""
-    import dataclasses
-    from repro.configs.base import get_config
-    from repro.models import blocks
-
-    cfg = get_config("qwen3_moe_235b").reduced()
-    e, c, d, ff = 4, 8, cfg.d_model, cfg.moe_d_ff
-    xe = jnp.asarray(RNG.normal(size=(e, c, d)).astype(np.float32))
-    wg = jnp.asarray(RNG.normal(size=(e, d, ff)).astype(np.float32) * 0.1)
-    wu = jnp.asarray(RNG.normal(size=(e, d, ff)).astype(np.float32) * 0.1)
-    wd = jnp.asarray(RNG.normal(size=(e, ff, d)).astype(np.float32) * 0.1)
-    y0 = blocks._expert_ffn(cfg, wg, wu, wd, xe)
-    y1 = blocks._expert_ffn(
-        dataclasses.replace(cfg, use_fusion=True), wg, wu, wd, xe)
-    np.testing.assert_allclose(np.asarray(y1, np.float32),
-                               np.asarray(y0, np.float32),
-                               rtol=1e-4, atol=1e-4)
-
-
 # ---------------------------------------------------------------------------
 # Graph simplification pass
 # ---------------------------------------------------------------------------

@@ -8,8 +8,7 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core.loops import LegalityError
 from repro.kernels import ops, ref
-from repro.kernels.block_spmm import (block_spmm_pallas, densify_to_bcsr,
-                                      grouped_matmul_pallas)
+from repro.kernels.block_spmm import block_spmm_pallas, densify_to_bcsr
 from repro.kernels.brgemm import brgemm_blocked_pallas, matmul_pallas, pick_tiles
 from repro.kernels.flash_attention import (flash_attention_pallas,
                                            flash_decode_pallas)
@@ -128,14 +127,18 @@ def test_property_block_spmm_random_patterns(seed):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_grouped_matmul(dtype):
-    T, d, f, E, bm = 64, 32, 64, 4, 8
+    """Rows sorted by group, each block of rows through its group's
+    matrix; rows past the groups come out zero; an empty group is fine."""
+    T, d, f = 40, 32, 64
+    sizes = np.array([7, 0, 20, 5], np.int32)
     x = jnp.asarray(RNG.normal(size=(T, d)).astype(np.float32), dtype)
-    gid = jnp.asarray(np.sort(RNG.integers(0, E, T // bm)).astype(np.int32))
-    w = jnp.asarray(RNG.normal(size=(E, d, f)).astype(np.float32), dtype)
-    out = grouped_matmul_pallas(x, gid, w, bf=32, interpret=True)
-    want = ref.grouped_matmul_ref(x, gid, w)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32), **_tol(dtype))
+    w = jnp.asarray(RNG.normal(size=(4, d, f)).astype(np.float32), dtype)
+    out = ops.grouped_matmul(x, w, jnp.asarray(sizes), out_dtype=jnp.float32)
+    gid = np.repeat(np.arange(4), sizes)
+    xf, wf = np.asarray(x, np.float32), np.asarray(w, np.float32)
+    want = np.zeros((T, f), np.float32)
+    want[:len(gid)] = np.einsum("td,tdf->tf", xf[:len(gid)], wf[gid])
+    np.testing.assert_allclose(np.asarray(out), want, **_tol(dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -225,6 +228,23 @@ def test_attention_xla_chunked_matches_oracle():
     for kw in (dict(causal=True), dict(causal=True, window=64),
                dict(causal=False)):
         a = ref.attention_xla_chunked(q, k, v, block_q=128, **kw)
+        b = ref.attention_ref(q, k, v, **kw)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+@pytest.mark.parametrize("block_kv", [64, 100])
+def test_attention_xla_chunked_key_blocks_match_oracle(block_kv):
+    """Keys in blocks with an online softmax (as rows past ``block_kv``
+    are taken), a block that does not divide the keys, and a value width
+    other than the query's (MLA's 192-wide q/k, 128-wide v)."""
+    B, H, Hk, S, D, Dv = 1, 4, 2, 512, 24, 16
+    q = jnp.asarray(RNG.normal(size=(B, H, S, D)).astype(np.float32))
+    k = jnp.asarray(RNG.normal(size=(B, Hk, S, D)).astype(np.float32))
+    v = jnp.asarray(RNG.normal(size=(B, Hk, S, Dv)).astype(np.float32))
+    for kw in (dict(causal=True), dict(causal=True, window=64),
+               dict(causal=False)):
+        a = ref.attention_xla_chunked(q, k, v, block_q=128,
+                                      block_kv=block_kv, **kw)
         b = ref.attention_ref(q, k, v, **kw)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 

@@ -140,17 +140,124 @@ def test_rope_partial_fraction():
     assert float(jnp.max(jnp.abs(full[..., 8:] - x[..., 8:]))) > 1e-4
 
 
-def test_moe_capacity_drops_tokens():
+def test_yarn_frequency_table_and_mscale():
+    """DeepSeek-V2's YaRN (64 rope dims, base 1e4, factor 40 over 4096
+    positions, beta 32 / 1): the ramp runs over pairs 10…23; below it the
+    original frequencies, above them ÷ 40; the softmax scale is
+    192^-1/2 · (0.1 · 0.707 · ln 40 + 1)^2; cos / sin are not scaled."""
     import dataclasses
-    cfg = dataclasses.replace(get_config("qwen3_moe_235b").reduced(),
-                              capacity_factor=0.5)
-    p = B.init_moe(cfg, KEY)
-    x = jnp.asarray(np.random.default_rng(0).normal(size=(64, cfg.d_model)),
+    cfg = get_config("deepseek_v2_236b")
+    y = cfg.rope_yarn
+    assert B.yarn_correction_range(y, 64, 1e4) == (10, 23)
+    f = B.rope_inv_freq(64, 1e4, y)
+    extra = 1e4 ** (-np.arange(32) / 32)
+    np.testing.assert_allclose(f[:10], extra[:10], rtol=1e-6)
+    np.testing.assert_allclose(f[23:], extra[23:] / 40, rtol=1e-6)
+    m = 1 - 6 / 13                              # pair 16: ramp 6/13
+    np.testing.assert_allclose(f[16], extra[16] * (m + (1 - m) / 40),
+                               rtol=1e-6)
+    assert B.yarn_mscale(40, 0.707) == pytest.approx(1.2608038, rel=1e-6)
+    assert B.mla_softmax_scale(cfg) == pytest.approx(
+        192 ** -0.5 * 1.2608038 ** 2, rel=1e-6)
+    plain = dataclasses.replace(cfg, rope_yarn=None)
+    assert B.mla_softmax_scale(plain) == pytest.approx(192 ** -0.5)
+    # the rotation at position p turns pair i by p · f[i]
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(1, 3, 1, 64)),
                     jnp.float32)
-    y_tight, _ = B.moe_apply(cfg, p, x)
-    cfg2 = dataclasses.replace(cfg, capacity_factor=1e9)
-    y_loose, _ = B.moe_apply(cfg2, p, x)
-    assert float(jnp.max(jnp.abs(y_tight - y_loose))) > 1e-6
+    pos = jnp.asarray([[0, 7, 5000]])
+    out = np.asarray(B.apply_rope(x, pos, theta=1e4, yarn=y))
+    ang = np.asarray(pos)[0, :, None] * f
+    x1, x2 = np.asarray(x)[0, :, 0, :32], np.asarray(x)[0, :, 0, 32:]
+    np.testing.assert_allclose(out[0, :, 0, :32],
+                               x1 * np.cos(ang) - x2 * np.sin(ang),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(out[0, :, 0, 32:],
+                               x1 * np.sin(ang) + x2 * np.cos(ang),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_group_limited_routing():
+    """Softmax over every expert; the k picks lie in the topk_group groups
+    whose best member scores highest; weights are the scores × the routed
+    scale (DeepSeek-V2), or renormalised (qwen3)."""
+    cfg = get_config("deepseek_v2_236b")
+    t = 64
+    logits = jnp.asarray(np.random.default_rng(1).normal(
+        size=(t, cfg.num_experts)), jnp.float32)
+    probs, w, idx = B.moe_route(cfg, logits)
+    p = np.asarray(jax.nn.softmax(logits, -1))
+    np.testing.assert_allclose(np.asarray(probs), p, rtol=1e-6)
+    per = cfg.num_experts // cfg.n_group
+    for i in range(t):
+        best = p[i].reshape(cfg.n_group, per).max(-1)
+        groups = set(np.argsort(-best)[:cfg.topk_group])
+        allowed = np.concatenate([np.arange(g * per, (g + 1) * per)
+                                  for g in sorted(groups)])
+        want = allowed[np.argsort(-p[i, allowed])[:cfg.experts_per_tok]]
+        assert set(np.asarray(idx[i])) == set(want)
+        np.testing.assert_allclose(np.asarray(w[i]),
+                                   p[i, np.asarray(idx[i])] * 16, rtol=1e-6)
+    qcfg = get_config("qwen3_moe_235b")
+    _, w, idx = B.moe_route(qcfg, logits[:, :qcfg.num_experts])
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, rtol=1e-5)
+
+
+def _reduced_v2(**kw):
+    import dataclasses
+    return dataclasses.replace(get_config("deepseek_v2_236b").reduced(), **kw)
+
+
+def test_moe_is_dropless_under_skewed_routing():
+    """Every token routed to the same two held experts: each of the T·k
+    assignments is computed (no capacity), as the float32 reference adds
+    them up, and the counts say so."""
+    import mla_moe_reference as ref
+    cfg = _reduced_v2()
+    p = B.init_moe(cfg, KEY)
+    router = np.zeros((cfg.d_model, cfg.num_experts), np.float32)
+    router[:, [2, 4]] = 1.0          # held (1…5), in two different groups
+    p["router"] = jnp.asarray(router)
+    t = 48
+    x = jnp.asarray(np.abs(np.random.default_rng(2).normal(
+        size=(t, cfg.d_model))) + 0.5, jnp.float32)
+    y, aux = B.moe_apply(cfg, p, x)
+    with jax.default_matmul_precision("highest"):
+        want = ref.moe(cfg, p, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-4,
+                               atol=1e-4)
+    assert int(aux["moe_assignments"]) == t * cfg.experts_per_tok
+    assert int(aux["moe_experts_touched"]) == 2
+    _, aux = B.moe_apply(cfg, p, x, token_mask=jnp.arange(t) < 5)
+    assert int(aux["moe_assignments"]) == 5 * cfg.experts_per_tok
+
+
+def test_moe_shares_add_up_to_the_uncut_layer():
+    """Each device of an expert-parallel deployment holds a share of the
+    experts: the shares' routed parts, with the shared experts (which
+    every device computes alike) counted once, add up to the uncut layer,
+    and that layer matches the float32 reference."""
+    import mla_moe_reference as ref
+    whole = _reduced_v2(experts_held=0, first_expert=0)
+    p = B.init_moe(whole, KEY)
+    x = jnp.asarray(np.random.default_rng(3).normal(
+        size=(40, whole.d_model)), jnp.float32)
+    y_whole, aux_whole = B.moe_apply(whole, p, x)
+    shared = B.mlp_apply(whole, p["shared"], x)
+    parts, assigned = [], 0
+    for first, held in ((0, 3), (3, 1), (4, 4)):
+        cfg = _reduced_v2(experts_held=held, first_expert=first)
+        share = dict(p, **{n: p[n][first:first + held]
+                           for n in ("wg", "wu", "wd")})
+        y, aux = B.moe_apply(cfg, share, x)
+        parts.append(y - shared)
+        assigned += int(aux["moe_assignments"])
+    np.testing.assert_allclose(np.asarray(sum(parts) + shared),
+                               np.asarray(y_whole), rtol=1e-4, atol=1e-5)
+    assert assigned == int(aux_whole["moe_assignments"]) == 40 * 2
+    with jax.default_matmul_precision("highest"):
+        want = ref.moe(whole, p, x)
+    np.testing.assert_allclose(np.asarray(y_whole), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
 
 
 def test_mla_latent_cache_shape():

@@ -421,6 +421,86 @@ def test_decode_kv_pages_counts_live_pages():
     assert eng.registry.snapshot()["serve.decode_kv_pages"] == want
 
 
+def _v2_requests(cfg, rng, lengths):
+    return [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
+
+
+def test_v2_paged_prefill_then_decode_matches_float32_reference():
+    """DeepSeek-V2 at toy widths with YaRN, group-limited routing and a
+    partial expert share: the paged prefill (at a padded bucket) and then
+    paged decode give the logits of the plain float32 reference
+    (``mla_moe_reference``) over the whole sequence.  Both compute in
+    float32; 2e-4 leaves room for the absorbed decode's other order of
+    summation, far below the spread of the logits (std ≈ 1)."""
+    import mla_moe_reference as ref
+    cfg, params = _model("deepseek_v2_236b")
+    assert cfg.rope_yarn is not None and cfg.n_group > 1
+    assert cfg.held_experts < cfg.num_experts and cfg.first_expert > 0
+    rng = np.random.default_rng(5)
+    plen, steps, ps = 11, 9, 4
+    toks = rng.integers(0, cfg.vocab_size, plen + steps).tolist()
+    caches = lm.init_paged_cache(cfg, 1, 8, ps)
+    table = jnp.arange(8, dtype=jnp.int32)[None]
+    t = np.zeros((1, 16), np.int32)
+    t[0, :plen] = toks[:plen]
+    logits, caches = lm.prefill(cfg, params, caches, {"tokens": jnp.asarray(t)},
+                                page_table=table, page_size=ps,
+                                logit_index=jnp.asarray([plen - 1]))
+    got = [logits[0, :cfg.vocab_size]]
+    for i in range(steps - 1):
+        logits, caches = lm.decode_step(
+            cfg, params, caches, jnp.asarray([toks[plen + i]], jnp.int32),
+            jnp.asarray([plen + i], jnp.int32), page_table=table,
+            page_size=ps)
+        got.append(logits[0, :cfg.vocab_size])
+    want = ref.logits(cfg, params, toks[:plen + steps - 1])[plen - 1:]
+    np.testing.assert_allclose(np.asarray(jnp.stack(got)), np.asarray(want),
+                               atol=2e-4, rtol=2e-4)
+
+
+def test_v2_engine_matches_reference_and_counts_expert_work():
+    """Through ``Engine`` (scheduler, paged latent pool, decode segment):
+    every greedy token is the float32 reference's best to within 1e-4 of
+    a logit (float32 on both sides), and the segment's expert counters
+    equal what the reference's routing gives: assignments on held experts
+    over the decoded tokens and layers, and per iteration and layer the
+    held experts that some active slot's token chose."""
+    import mla_moe_reference as ref
+    cfg, params = _model("deepseek_v2_236b")
+    eng = Engine(cfg, params, EngineConfig(num_slots=2, page_size=4,
+                                           max_seq=32, segment_len=4))
+    rng = np.random.default_rng(6)
+    prompts = _v2_requests(cfg, rng, (9, 5))
+    budgets = (7, 4)
+    for uid, (pr, n) in enumerate(zip(prompts, budgets)):
+        eng.submit(pr, n, uid=uid)
+    out = eng.run()
+    held = np.arange(cfg.first_expert, cfg.first_expert + cfg.held_experts)
+    chosen = {}                        # (uid, decode step) → [set per layer]
+    for uid, pr in enumerate(prompts):
+        full = out[uid]
+        gates = []
+        lg = np.asarray(ref.logits(cfg, params, full[:-1], gates))
+        pos = np.arange(len(pr) - 1, len(full) - 1)
+        gap = lg[pos].max(-1) - lg[pos, np.asarray(full[len(pr):])]
+        assert gap.max() < 1e-4, gap
+        for i, p in enumerate(range(len(pr), len(full) - 1)):
+            chosen[uid, i] = [set(np.flatnonzero(np.asarray(g[p]))) & set(held)
+                              for g in gates]
+    assert eng.moe_assignments == sum(len(s) for v in chosen.values()
+                                      for s in v)
+    touched = 0
+    for i in range(max(budgets) - 1):
+        per_layer = [v for (uid, j), v in chosen.items() if j == i]
+        touched += sum(len(set().union(*layer)) for layer in zip(*per_layer))
+    assert eng.moe_experts_touched == touched
+    assert eng.moe_assignments > 0
+    # latent pages: the same live-page count as any paged attention
+    want = sum(-(-(len(p) + g) // 4) for uid, p in enumerate(prompts)
+               for g in range(1, budgets[uid]))
+    assert eng.decode_kv_pages == want
+
+
 def test_engine_programs_keyed_by_backend():
     """An engine runs programs traced under the kernel backend it was built
     with: same config, other backend → other programs; the backend active

@@ -137,3 +137,60 @@ def test_fused_attn_out_residual_compiles(one_chip, strict_fusion, m):
         lambda o, wo, res: fused_attn_out_apply(o, wo, residual=res,
                                                 backend="pallas"),
         one_chip, ((m, H * HD), BF16), ((H * HD, D), BF16), ((m, D), BF16))
+
+
+def _v2():
+    """DeepSeek-V2 at its published widths, one chip's share: 10 of the
+    160 experts the router scores."""
+    import dataclasses
+    from repro.configs import get_config
+    return dataclasses.replace(get_config("deepseek_v2_236b"),
+                               experts_held=10, first_expert=0)
+
+
+def _bf16_shapes(tree, sharding):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, BF16, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("tokens", [32, 4096], ids=["decode", "prefill"])
+def test_moe_grouped_product_compiles(one_chip, tokens):
+    """One expert layer at DeepSeek-V2's widths (d 5120, 10 held experts
+    of width 1536, 2 shared, the router over 160): group-limited routing
+    and the dropless grouped product compile, the product as the TPU's
+    ragged-dot kernel."""
+    from repro.models import blocks as B
+    cfg = _v2()
+    p = _bf16_shapes(jax.eval_shape(lambda k: B.init_moe(cfg, k),
+                                    jax.random.PRNGKey(0)), one_chip)
+    x = jax.ShapeDtypeStruct((tokens, cfg.d_model), BF16, sharding=one_chip)
+    text = jax.jit(lambda p, x: B.moe_apply(cfg, p, x)).lower(
+        p, x).compile().as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+
+
+def test_mla_paged_decode_layer_compiles(one_chip):
+    """One latent-attention layer decoding 32 slots over a latent page pool
+    of 8,192 positions each (576 lanes a token), at DeepSeek-V2's widths:
+    it compiles, and its arguments and temporaries fit one v5e."""
+    from repro.models import blocks as B
+    cfg = _v2()
+    slots, maxp = 32, 8192 // PAGE
+    p = _bf16_shapes(jax.eval_shape(lambda k: B.init_mla(cfg, k),
+                                    jax.random.PRNGKey(0)), one_chip)
+    lanes = cfg.kv_lora_rank + cfg.rope_head_dim
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((slots, 1, cfg.d_model), BF16),
+        ((slots * maxp + 1, PAGE, lanes), BF16),
+        ((slots,), I32), ((slots, maxp), I32))]
+
+    def layer(p, x, pool, pos, table):
+        return B.mla_apply(cfg, p, x, positions=pos[:, None],
+                           cache={"latent": pool}, cache_pos=pos,
+                           page_table=table, page_size=PAGE)
+
+    compiled = jax.jit(layer).lower(p, *args).compile()
+    m = compiled.memory_analysis()
+    used = m.argument_size_in_bytes + m.temp_size_in_bytes
+    assert used < 15.75 * 2 ** 30, used
