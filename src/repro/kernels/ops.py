@@ -29,6 +29,7 @@ import functools
 import os
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 
@@ -126,9 +127,12 @@ def decode_attention(q, k_cache, v_cache, *, length=None, window=None,
 
 @scoped("ops.paged_decode_attention")
 def paged_decode_attention(q, k_pool, v_pool, page_table, *, page_size,
-                           length, window=None, out_dtype=None, backend=None):
-    """Decode attention over token-major page pools (P, page_size, Hk·D)
-    indexed by ``page_table`` (B, maxp) — the serving engine's KV layout.
+                           length, layer=0, window=None, out_dtype=None,
+                           backend=None):
+    """Decode attention over token-major page pools (L, P, page_size, Hk·D),
+    stacked over a layer group, read at ``layer`` (int or int32 scalar) —
+    or one layer's (P, page_size, Hk·D) — and indexed by ``page_table``
+    (B, maxp): the serving engine's KV layout.
 
     On a TPU this is ``paged_decode_pallas`` whatever the backend says:
     the kernel reads each slot's live pages in place, where any XLA
@@ -138,20 +142,23 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, *, page_size,
     kernel in interpret mode."""
     from repro.kernels.paged_attention import paged_decode_pallas
     kw = dict(page_size=page_size, window=window, out_dtype=out_dtype)
+    layer = jnp.asarray(layer, jnp.int32)
     if (backend or current_backend()) == "pallas_interpret":
         return paged_decode_pallas(q, k_pool, v_pool, page_table,
-                                   length=length, interpret=True, **kw)
+                                   length=length, layer=layer,
+                                   interpret=True, **kw)
 
-    def kernel(q, k_pool, v_pool, page_table, length):
+    def kernel(q, k_pool, v_pool, page_table, length, layer):
         return paged_decode_pallas(q, k_pool, v_pool, page_table,
-                                   length=length, **kw)
+                                   length=length, layer=layer, **kw)
 
-    def reference(q, k_pool, v_pool, page_table, length):
+    def reference(q, k_pool, v_pool, page_table, length, layer):
         return _ref.paged_decode_attention_ref(q, k_pool, v_pool, page_table,
-                                               length=length, **kw)
+                                               length=length, layer=layer,
+                                               **kw)
 
     return jax.lax.platform_dependent(q, k_pool, v_pool, page_table, length,
-                                      tpu=kernel, default=reference)
+                                      layer, tpu=kernel, default=reference)
 
 
 @scoped("ops.mamba_scan")

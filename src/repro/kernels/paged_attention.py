@@ -1,11 +1,13 @@
 """Paged decode attention: one Pallas TPU kernel over the live pages.
 
 One query token per slot attends to that slot's pages, read in place from
-the serving engine's token-major page pools ``(P, page_size, Hk·D)``.  The
-page table and the lengths are scalar-prefetch operands; the pools stay in
-HBM (``memory_space=pl.ANY``) and each live page is copied by its own async
-DMA into double-buffered VMEM.  Nothing builds a per-slot padded view, and
-K/V never reach HBM in float32.
+the serving engine's token-major page pools ``(L, P, page_size, Hk·D)``,
+stacked over the layers of a layer group.  The page table, the lengths and
+the layer are scalar-prefetch operands; the pools stay in HBM
+(``memory_space=pl.ANY``) and each live page of the layer is copied by its
+own async DMA into double-buffered VMEM.  Nothing builds a per-slot padded
+view or slices the layer out of the stack, and K/V never reach HBM in
+float32.
 
 Grid ``(slot, KV block)``; a block is ``pages_per_block`` pages.  Blocks
 that hold no live page (past ``ceil(length / page_size)``, or before the
@@ -46,15 +48,18 @@ def block_pages(page_size: int, row_bytes: int, maxp: int) -> int:
 
 
 def paged_decode_pallas(q, k_pool, v_pool, page_table, *, page_size, length,
-                        window=None, out_dtype=None, pages_per_block=None,
-                        interpret=False):
+                        layer=0, window=None, out_dtype=None,
+                        pages_per_block=None, interpret=False):
     """Single-token decode over paged pools, the contract of
-    ``ref.paged_decode_attention_ref``: q (B, H, D); pools (P, page_size,
-    Hk·D); ``page_table`` (B, maxp) int32; ``length`` (B,) valid prefix
-    lengths, each at least 1.  ``pages_per_block`` defaults to
+    ``ref.paged_decode_attention_ref``: q (B, H, D); pools (L, P, page_size,
+    Hk·D), of which ``layer`` (int or int32 scalar) is read, or one layer's
+    (P, page_size, Hk·D); ``page_table`` (B, maxp) int32; ``length`` (B,)
+    valid prefix lengths, each at least 1.  ``pages_per_block`` defaults to
     :func:`block_pages` of the shapes."""
+    if k_pool.ndim == 3:
+        k_pool, v_pool = k_pool[None], v_pool[None]
     b, h, d = q.shape
-    _, ps, hkd = k_pool.shape
+    _, _, ps, hkd = k_pool.shape
     assert ps == page_size and v_pool.shape == k_pool.shape
     hk = hkd // d
     g = h // hk
@@ -84,8 +89,9 @@ def paged_decode_pallas(q, k_pool, v_pool, page_table, *, page_size, length,
         lo, hi = live_pages(lens, slot)
         return lo // ppb, (hi + ppb - 1) // ppb
 
-    def kernel(len_ref, table_ref, qbd_ref, sel_ref, fold_ref, k_src, v_src,
-               o_ref, kbuf, vbuf, sems, cur_ref, m_ref, l_ref, acc_ref):
+    def kernel(len_ref, table_ref, layer_ref, qbd_ref, sel_ref, fold_ref,
+               k_src, v_src, o_ref, kbuf, vbuf, sems, cur_ref, m_ref, l_ref,
+               acc_ref):
         slot, j = pl.program_id(0), pl.program_id(1)
 
         def block_copies(at_slot, blk, buf, act):
@@ -98,7 +104,8 @@ def paged_decode_pallas(q, k_pool, v_pool, page_table, *, page_size, length,
                 rows = pl.ds(pl.multiple_of((p - blk * ppb) * ps, ps), ps)
                 for kv, (pool, dst) in enumerate(((k_src, kbuf),
                                                   (v_src, vbuf))):
-                    act(pltpu.make_async_copy(pool.at[page], dst.at[buf, rows],
+                    act(pltpu.make_async_copy(pool.at[layer_ref[0], page],
+                                              dst.at[buf, rows],
                                               sems.at[kv, buf]))
                 return carry
 
@@ -168,7 +175,7 @@ def paged_decode_pallas(q, k_pool, v_pool, page_table, *, page_size, length,
     fn = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, nblk),
             in_specs=[
                 pl.BlockSpec((1, h, hkd), per_slot),
@@ -194,6 +201,7 @@ def paged_decode_pallas(q, k_pool, v_pool, page_table, *, page_size, length,
         interpret=interpret,
     )
     return fn(jnp.asarray(length, jnp.int32).reshape(b),
-              page_table.astype(jnp.int32).reshape(-1), qbd,
+              page_table.astype(jnp.int32).reshape(-1),
+              jnp.asarray(layer, jnp.int32).reshape(1), qbd,
               jnp.asarray(sel, jnp.float32), jnp.asarray(fold, jnp.float32),
               k_pool, v_pool)

@@ -146,11 +146,13 @@ def decode_attention_ref(q, k_cache, v_cache, *, length=None, window=None,
 
 
 def paged_decode_attention_ref(q, k_pool, v_pool, page_table, *, page_size,
-                               length, window=None, out_dtype=None):
+                               length, layer=0, window=None, out_dtype=None):
     """Single-token decode over a paged KV pool: q (B, H, D); pools are
-    token-major page pools (P, page_size, Hk·D) shared by every slot;
-    ``page_table`` (B, maxp) int32 names each slot's pages (trash-page
-    sentinel in unused entries); ``length`` (B,) valid prefix lengths.
+    token-major page pools (L, P, page_size, Hk·D) stacked over a layer
+    group and read at ``layer`` (int or int32 scalar), or one layer's
+    (P, page_size, Hk·D), shared by every slot; ``page_table`` (B, maxp)
+    int32 names each slot's pages (trash-page sentinel in unused entries);
+    ``length`` (B,) valid prefix lengths.
 
     Token-major pools keep the decode *write* a natural (page, offset) row
     scatter; for the read, the gathered view is swapped to the head-major
@@ -159,10 +161,12 @@ def paged_decode_attention_ref(q, k_pool, v_pool, page_table, *, page_size,
     strides over heads and scalarizes the dot on CPU (measured ~4× the
     whole attention cost).  Positions beyond ``length`` read reserved /
     trash pages and are masked by :func:`decode_attention_ref`."""
+    if k_pool.ndim == 3:
+        k_pool, v_pool = k_pool[None], v_pool[None]
     b, maxp = page_table.shape
-    s, hk = maxp * page_size, k_pool.shape[2] // q.shape[-1]
-    k = jnp.swapaxes(k_pool[page_table].reshape(b, s, hk, -1), 1, 2)
-    v = jnp.swapaxes(v_pool[page_table].reshape(b, s, hk, -1), 1, 2)
+    s, hk = maxp * page_size, k_pool.shape[-1] // q.shape[-1]
+    k = jnp.swapaxes(k_pool[layer, page_table].reshape(b, s, hk, -1), 1, 2)
+    v = jnp.swapaxes(v_pool[layer, page_table].reshape(b, s, hk, -1), 1, 2)
     return decode_attention_ref(q, k, v, length=length, window=window,
                                 out_dtype=out_dtype)
 

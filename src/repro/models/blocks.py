@@ -156,7 +156,7 @@ def init_attention(cfg: ModelConfig, key):
 def attention_apply(cfg: ModelConfig, p, x, *, kind: str = "attn",
                     positions=None, cache=None, cache_pos=None,
                     xattn_kv=None, residual=None, dropout_seed=None,
-                    page_table=None, page_size: int = 0):
+                    page_table=None, page_size: int = 0, layer=0):
     """x (B, S, d).  kind ∈ {attn, local, global, bidir, cross}.
 
     Training/prefill: cache None.  Decode: S == 1, ``cache`` = dict(k, v)
@@ -165,12 +165,15 @@ def attention_apply(cfg: ModelConfig, p, x, *, kind: str = "attn",
     sits at its own sequence length; attention masks by ``pos + 1``).
 
     Paged mode (``page_table`` (B, maxp) int32 + static ``page_size``): the
-    cache arrays are token-major page *pools* (P, page_size, Hk·hd) shared
-    by all slots;
-    a slot's logical sequence lives in the pages its table row names.  Decode
-    scatters the new K/V into (page, offset) and attends over the gathered
-    per-slot view; prefill (S > 1, from position 0) attends over the in-chunk
-    K/V and records them into the slot's pages for later decode.
+    cache arrays are token-major page *pools* (L, P, page_size, Hk·hd)
+    stacked over the layers of a layer group and shared by all slots; this
+    layer's pool is ``layer`` (int or int32 scalar) of the stack, which is
+    never sliced out: the new rows are written into it in place and the
+    whole stack is returned.  A slot's logical sequence lives in the pages
+    its table row names.  Decode scatters the new K/V into (layer, page,
+    offset) and attends over the slot's live pages; prefill (S > 1, from
+    position 0) attends over the in-chunk K/V and records them into the
+    slot's pages for later decode.
     ``residual`` (B, S, d): when given, the block residual is folded into
     the output projection — with ``cfg.use_fusion`` it rides the
     ``fused_attn_out_graph`` ``+residual`` tail inside the same kernel as
@@ -224,12 +227,15 @@ def attention_apply(cfg: ModelConfig, p, x, *, kind: str = "attn",
             assert pos.ndim == 1, "paged decode takes per-slot (B,) positions"
             pg = _page_lookup(page_table, (pos // page_size)[:, None])[:, 0]
             off = jnp.mod(pos, page_size)
-            k_pool = cache["k"].at[pg, off].set(k[:, :, 0].reshape(b, hk * hd))
-            v_pool = cache["v"].at[pg, off].set(v[:, :, 0].reshape(b, hk * hd))
+            k_pool = cache["k"].at[layer, pg, off].set(
+                k[:, :, 0].reshape(b, hk * hd))
+            v_pool = cache["v"].at[layer, pg, off].set(
+                v[:, :, 0].reshape(b, hk * hd))
             new_cache = {"k": k_pool, "v": v_pool}
             o = ops.paged_decode_attention(
                 q[:, :, 0], k_pool, v_pool, page_table,
-                page_size=page_size, length=pos + 1, window=window)
+                page_size=page_size, length=pos + 1, layer=layer,
+                window=window)
             o = o[:, :, None]
         else:
             # whole-prompt prefill (position 0): attention runs on the
@@ -241,8 +247,10 @@ def attention_apply(cfg: ModelConfig, p, x, *, kind: str = "attn",
             pg = _page_lookup(page_table,
                               jnp.broadcast_to(tpos // page_size, (b, s)))
             off = jnp.broadcast_to(jnp.mod(tpos, page_size), (b, s))
-            k_pool = cache["k"].at[pg, off].set(xk.reshape(b, s, hk * hd))
-            v_pool = cache["v"].at[pg, off].set(xv.reshape(b, s, hk * hd))
+            k_pool = cache["k"].at[layer, pg, off].set(
+                xk.reshape(b, s, hk * hd))
+            v_pool = cache["v"].at[layer, pg, off].set(
+                xv.reshape(b, s, hk * hd))
             new_cache = {"k": k_pool, "v": v_pool}
             o = ops.attention(q, k, v, causal=causal, window=window)
     elif cache is not None and kind != "cross":
@@ -367,7 +375,7 @@ def mla_softmax_scale(cfg: ModelConfig) -> float:
 
 @ops.scoped("mla")
 def mla_apply(cfg: ModelConfig, p, x, *, positions=None, cache=None,
-              cache_pos=None, page_table=None, page_size: int = 0):
+              cache_pos=None, page_table=None, page_size: int = 0, layer=0):
     """Multi-head Latent Attention.  The KV cache stores only the compressed
     latent (kv_lora + rope_head_dim) per position — the paper-exact memory
     saving.  Train/prefill re-expands K/V through wkv_b; decode uses the
@@ -377,8 +385,9 @@ def mla_apply(cfg: ModelConfig, p, x, *, positions=None, cache=None,
 
     ``cache_pos`` may be a ``(B,)`` vector of per-slot positions (continuous
     batching).  Paged mode (``page_table`` + ``page_size``): the cache is a
-    latent page pool (P, page_size, kvr+rd) shared by all slots — see
-    :func:`attention_apply`."""
+    latent page pool (L, P, page_size, lanes) stacked over a layer group,
+    read and written at ``layer`` in place — see :func:`attention_apply`;
+    a row holds the kvr+rd latent lanes, zero-padded to ``lanes``."""
     dt = compute_dtype(cfg)
     b, s, d = x.shape
     h, hd, rd, kvr = cfg.num_heads, cfg.head_dim, cfg.rope_head_dim, cfg.kv_lora_rank
@@ -407,12 +416,17 @@ def mla_apply(cfg: ModelConfig, p, x, *, positions=None, cache=None,
     paged = page_table is not None
     if cache is not None and paged:
         assert page_size > 0, "paged cache needs a static page_size"
+        lanes = cache["latent"].shape[-1]
+        lat_rows = jnp.pad(latent, ((0, 0), (0, 0), (0, lanes - kvr - rd)))
         if s == 1:
             pos = jnp.asarray(cache_pos, jnp.int32)
             assert pos.ndim == 1, "paged decode takes per-slot (B,) positions"
             pg = _page_lookup(page_table, (pos // page_size)[:, None])[:, 0]
-            pool = cache["latent"].at[pg, jnp.mod(pos, page_size)].set(
-                latent[:, 0])
+            pool = cache["latent"].at[layer, pg, jnp.mod(pos, page_size)].set(
+                lat_rows[:, 0])
+            maxp = page_table.shape[-1]
+            lat_cache = pool[layer, page_table].reshape(
+                b, maxp * page_size, -1)
         else:
             if isinstance(cache_pos, int):
                 assert cache_pos == 0, "paged prefill starts at position 0"
@@ -420,11 +434,9 @@ def mla_apply(cfg: ModelConfig, p, x, *, positions=None, cache=None,
             pg = _page_lookup(page_table,
                               jnp.broadcast_to(tpos // page_size, (b, s)))
             pool = cache["latent"].at[
-                pg, jnp.broadcast_to(jnp.mod(tpos, page_size), (b, s))
-            ].set(latent)
+                layer, pg, jnp.broadcast_to(jnp.mod(tpos, page_size), (b, s))
+            ].set(lat_rows)
         new_cache = {"latent": pool}
-        maxp = page_table.shape[-1]
-        lat_cache = pool[page_table].reshape(b, maxp * page_size, -1)
     elif cache is not None:
         if jnp.ndim(cache_pos) == 1:
             assert s == 1, "vector cache_pos is decode-only (S == 1)"
@@ -437,7 +449,7 @@ def mla_apply(cfg: ModelConfig, p, x, *, positions=None, cache=None,
         new_cache = {"latent": lat_cache}
     if cache is not None and s == 1:
         smax = lat_cache.shape[1]
-        c_all, kr_all = lat_cache[..., :kvr], lat_cache[..., kvr:]
+        c_all, kr_all = lat_cache[..., :kvr], lat_cache[..., kvr:kvr + rd]
         wkv_b = pw["wkv_b"].reshape(kvr, h, 2 * hd)
         wk_b, wv_b = wkv_b[..., :hd], wkv_b[..., hd:]
         # absorb wk_b into the query: (B,h,kvr)

@@ -103,12 +103,14 @@ def block_apply(cfg: ModelConfig, p, x, *, kind: str, moe: bool,
                 cache=None, cache_pos=0, positions=None, xattn_kv=None,
                 ep_axis: Optional[str] = None, dropout_seed=None,
                 page_table=None, page_size: int = 0, seq_lengths=None,
-                token_mask=None):
+                token_mask=None, layer=0):
     """Pre-norm residual block.  ``dropout_seed`` (train only, already
     folded per layer) enables the attention-output dropout at
     ``cfg.dropout_rate``.  ``cache_pos`` may be a per-slot ``(B,)`` vector
     and ``page_table``/``page_size`` switch the attention caches to the
-    paged pool layout (see ``blocks.attention_apply``); mamba state stays
+    paged pool layout (see ``blocks.attention_apply``): the cache's pools
+    are then stacked over the layer group and this block's is ``layer`` of
+    the stack; mamba state stays
     per-slot but honours ``seq_lengths`` ((B,) valid-token counts) so
     bucket-padded prefill leaves exact SSM state.  ``token_mask`` ((B,)
     bool) says which sequences the expert layer's counts count.
@@ -127,7 +129,7 @@ def block_apply(cfg: ModelConfig, p, x, *, kind: str, moe: bool,
         out, c = B.mla_apply(cfg, p["mla"], h, positions=positions,
                              cache=cache.get("mla") if cache else None,
                              cache_pos=cache_pos, page_table=page_table,
-                             page_size=page_size)
+                             page_size=page_size, layer=layer)
         if new_cache is not None:
             new_cache["mla"] = c
     else:
@@ -143,7 +145,7 @@ def block_apply(cfg: ModelConfig, p, x, *, kind: str, moe: bool,
                                    residual=x if res_folded else None,
                                    dropout_seed=dropout_seed,
                                    page_table=page_table,
-                                   page_size=page_size)
+                                   page_size=page_size, layer=layer)
         if new_cache is not None:
             new_cache["attn"] = c
     x = out if res_folded else x + out
@@ -254,6 +256,22 @@ def init_params(cfg: ModelConfig, key):
 # Forward
 # --------------------------------------------------------------------------
 
+# the cache entries of a block that are page pools when caches are paged
+_POOL_KEYS = ("attn", "mla")
+
+
+def _split_pools(pcache):
+    """A period's caches (one dict per block) → (its page pools, the rest:
+    mamba state), each a list of dicts of the same length."""
+    pools = [{k: c[k] for k in c if k in _POOL_KEYS} for c in pcache]
+    rest = [{k: c[k] for k in c if k not in _POOL_KEYS} for c in pcache]
+    return pools, rest
+
+
+def _merge_pools(pools, rest):
+    return [{**p, **r} for p, r in zip(pools, rest)]
+
+
 def _apply_groups(cfg, gparams_list, groups, x, *, caches=None, cache_pos=0,
                   positions=None, xattn_kv=None, ep_axis=None, remat=True,
                   cross=False, unroll=False, dropout_seed=None,
@@ -265,6 +283,14 @@ def _apply_groups(cfg, gparams_list, groups, x, *, caches=None, cache_pos=0,
     the dry-run so ``compiled.cost_analysis()`` counts every layer (XLA's
     analysis reports a while-loop body once), at the cost of HLO size.
 
+    Paged caches (``page_table`` given): the attention / latent page pools,
+    stacked over the group's repeat axis, are never split by layer.  They
+    ride the scan's carry (the unrolled loop's running value) whole, and
+    each block writes its new rows into them in place at its index in the
+    group (traced in the scan, static unrolled) — slicing a layer out and
+    stacking it back would copy every pool each step.  Mamba state, dense
+    caches and training keep the ``xs``/``ys`` layout.
+
     ``dropout_seed`` (traced uint32 scalar) is folded with the absolute
     layer index (``fusion.rng.fold_in``) so every layer draws an independent
     dropout stream from one seed — identical across fused/unfused paths and
@@ -272,10 +298,19 @@ def _apply_groups(cfg, gparams_list, groups, x, *, caches=None, cache_pos=0,
     total_aux = aux_zero(cfg)
     new_caches = []
     layer_base = 0
+    paged = page_table is not None and caches is not None
     for gi, (gparams, group) in enumerate(zip(gparams_list, groups)):
         gcache = caches[gi] if caches is not None else None
+        pools = None
+        if paged:
+            pools, gcache = _split_pools(gcache)
 
-        def period(x, pparams, pcache, lidx0):
+        def period(x, pools, pparams, pcache, lidx0, layer):
+            """One period of blocks.  ``pcache`` is this layer's slice of
+            the caches but for the pools, which (paged) pass in and out
+            whole."""
+            if pools is not None:
+                pcache = _merge_pools(pools, pcache)
             aux_p = aux_zero(cfg)
             ncache = [] if pcache is not None else None
             for pos_i, (kind, moe) in enumerate(group.kinds):
@@ -289,7 +324,7 @@ def _apply_groups(cfg, gparams_list, groups, x, *, caches=None, cache_pos=0,
                              xattn_kv=xattn_kv, ep_axis=ep_axis,
                              dropout_seed=seed_i, page_table=page_table,
                              page_size=page_size, seq_lengths=seq_lengths,
-                             token_mask=token_mask)
+                             token_mask=token_mask, layer=layer)
                 if remat:
                     fn = jax.checkpoint(
                         fn, policy=jax.checkpoint_policies.nothing_saveable,
@@ -303,7 +338,9 @@ def _apply_groups(cfg, gparams_list, groups, x, *, caches=None, cache_pos=0,
                 if ncache is not None:
                     ncache.append(c)
                 aux_p = _add(aux_p, aux)
-            return x, ncache, aux_p
+            if pools is not None:
+                pools, ncache = _split_pools(ncache)
+            return x, pools, ncache, aux_p
 
         period_len = len(group.kinds)
         if group.repeat == 1 or unroll:
@@ -312,26 +349,27 @@ def _apply_groups(cfg, gparams_list, groups, x, *, caches=None, cache_pos=0,
                 pparams = jax.tree.map(lambda a: a[r], gparams)
                 pcache = (jax.tree.map(lambda a: a[r], gcache)
                           if gcache is not None else None)
-                x, ncache, aux_p = period(
-                    x, pparams, pcache, layer_base + r * period_len)
+                x, pools, ncache, aux_p = period(
+                    x, pools, pparams, pcache, layer_base + r * period_len, r)
                 total_aux = _add(total_aux, aux_p)
                 if ncache is not None:
                     ncaches_list.append(ncache)
-            new_caches.append(
-                jax.tree.map(lambda *xs: jnp.stack(xs), *ncaches_list)
-                if ncaches_list else None)
+            ncaches = (jax.tree.map(lambda *xs: jnp.stack(xs), *ncaches_list)
+                       if ncaches_list else None)
         else:
             def scan_body(carry, xs):
-                x, aux_acc = carry
-                pparams, pcache, lidx0 = xs
-                x, ncache, aux_p = period(x, pparams, pcache, lidx0)
-                return (x, _add(aux_acc, aux_p)), ncache
+                x, aux_acc, pools = carry
+                pparams, pcache, lidx0, layer = xs
+                x, pools, ncache, aux_p = period(
+                    x, pools, pparams, pcache, lidx0, layer)
+                return (x, _add(aux_acc, aux_p), pools), ncache
 
             lidx = layer_base + jnp.arange(group.repeat) * period_len
-            xs = (gparams, gcache, lidx)
-            (x, total_aux), ncaches = jax.lax.scan(
-                scan_body, (x, total_aux), xs)
-            new_caches.append(ncaches)
+            layers = jnp.arange(group.repeat) if paged else None
+            xs = (gparams, gcache, lidx, layers)
+            (x, total_aux, pools), ncaches = jax.lax.scan(
+                scan_body, (x, total_aux, pools), xs)
+        new_caches.append(_merge_pools(pools, ncaches) if paged else ncaches)
         layer_base += group.repeat * period_len
     return x, new_caches if caches is not None else None, total_aux
 
@@ -552,8 +590,13 @@ def init_paged_cache(cfg: ModelConfig, num_slots: int, num_pages: int,
                                jnp.float32),
             }
         elif cfg.use_mla:
-            c["mla"] = {"latent": jnp.zeros(
-                (rows, page_size, cfg.kv_lora_rank + cfg.rope_head_dim), dt)}
+            # a latent row padded to whole 128-lane tiles (DeepSeek-V2's
+            # 576 to 640), as the TPU's (8·k, 128) tiling lays it anyway:
+            # unpadded, its default layout puts the rows axis minor, and
+            # each program then copies the whole pool into a row-major
+            # layout and back
+            lanes = -(-(cfg.kv_lora_rank + cfg.rope_head_dim) // 128) * 128
+            c["mla"] = {"latent": jnp.zeros((rows, page_size, lanes), dt)}
         else:
             # token-major (rows, page_size, hk·hd), heads folded into the
             # last axis: the TPU tiles an array's last two dims by

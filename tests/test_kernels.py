@@ -190,29 +190,42 @@ def test_flash_decode_lengths_and_window():
 @pytest.mark.parametrize("pages_per_block", [None, 3],
                          ids=["derived", "3_of_8"])
 @pytest.mark.parametrize("window", [None, 10], ids=["full", "window10"])
-@pytest.mark.parametrize("h,hk,d", [(2, 2, 64), (16, 1, 128), (8, 2, 64)],
-                         ids=["mha_g1_d64", "gqa_g16_d128", "gqa_g4_d64"])
-def test_paged_decode_pallas_matches_reference(h, hk, d, window,
+@pytest.mark.parametrize("h,hk,d,stack", [
+    (2, 2, 64, None), (16, 1, 128, None), (8, 2, 64, None),
+    (36, 36, 64, (3, 2)), (32, 2, 128, (4, 1)),
+], ids=["mha_g1_d64", "gqa_g16_d128", "gqa_g4_d64",
+        "mha_36x64_layer2_of3", "gqa_32_2x128_layer1_of4"])
+def test_paged_decode_pallas_matches_reference(h, hk, d, stack, window,
                                                pages_per_block):
     """The paged kernel reads the live pages in place and agrees with the
     gather reference: lengths 1, one page, one page + 1, a partial last
     block and the whole table; shuffled, non-contiguous pages; one slot
     whose table is all trash page; blocks of 3 pages of 8 (a partial last
-    block, and windowed slots that skip their first blocks)."""
+    block, and windowed slots that skip their first blocks).  With
+    ``stack`` (layers, layer) the pools are stacked over a layer group
+    (L, P, page, Hk·D) and the kernel reads the given layer of the stack:
+    it must agree with the reference over that layer's pool alone."""
     ps, maxp = 8, 8
     lens = [1, ps, ps + 1, 21, maxp * ps, 13]
     b = len(lens)
     rows = b * maxp + 1                        # the last row is the trash page
+    lead = (stack[0],) if stack else ()
     q = jnp.asarray(RNG.normal(size=(b, h, d)).astype(np.float32))
-    k_pool = jnp.asarray(RNG.normal(size=(rows, ps, hk * d)).astype(np.float32))
-    v_pool = jnp.asarray(RNG.normal(size=(rows, ps, hk * d)).astype(np.float32))
+    k_pool = jnp.asarray(
+        RNG.normal(size=lead + (rows, ps, hk * d)).astype(np.float32))
+    v_pool = jnp.asarray(
+        RNG.normal(size=lead + (rows, ps, hk * d)).astype(np.float32))
     table = RNG.permutation(rows - 1)[:b * maxp].reshape(b, maxp)
     table[-1] = rows - 1
     table = jnp.asarray(table, jnp.int32)
     length = jnp.asarray(lens, jnp.int32)
+    layer = {"layer": stack[1]} if stack else {}
     out = paged_decode_pallas(q, k_pool, v_pool, table, page_size=ps,
                               length=length, window=window,
-                              pages_per_block=pages_per_block, interpret=True)
+                              pages_per_block=pages_per_block,
+                              interpret=True, **layer)
+    if stack:
+        k_pool, v_pool = k_pool[stack[1]], v_pool[stack[1]]
     want = ref.paged_decode_attention_ref(q, k_pool, v_pool, table,
                                           page_size=ps, length=length,
                                           window=window)

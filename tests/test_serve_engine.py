@@ -398,6 +398,57 @@ def test_engine_paged_kernel_matches_xla(arch):
         len(p) + n for p, n in reqs]
 
 
+@pytest.mark.parametrize("arch,layers", [
+    ("minicpm_2b", 2), ("deepseek_v2_236b", 3), ("jamba_1_5_large", 16)])
+def test_prefill_writes_only_its_slot_pages(arch, layers):
+    """A batch-1 prefill into one slot writes that slot's pages, and its
+    bucket padding past them the trash page, in every layer of each
+    stacked pool (the rows land at (layer, page, offset)); every other
+    page of every layer, and the other slots' mamba state, stay as they
+    were.  Each model is deep enough that a group's pools stack two
+    layers or more (MLA: the dense layer, then 2 expert layers; the
+    hybrid: 2 periods of mamba and attention)."""
+    import dataclasses
+    cfg = dataclasses.replace(_model(arch)[0], num_layers=layers)
+    params = lm.init_params(cfg, KEY)
+    ps, slot = 4, 1
+    eng = Engine(cfg, params, EngineConfig(num_slots=3, page_size=ps,
+                                           max_seq=16))
+    key = jax.random.PRNGKey(3)
+    before = jax.tree.map(
+        lambda a: jax.random.normal(jax.random.fold_in(key, a.size),
+                                    a.shape).astype(a.dtype), eng.caches)
+    rows = eng.kv.num_pages + 1
+    trash = rows - 1
+    pages = [9, 2]                     # the slot's pages, out of order
+    plen = 7                           # 2 pages; the bucket of 16 has 4
+    table_row = jnp.asarray([pages + [trash, trash]], jnp.int32)
+    tokens = jnp.asarray([[5 + i for i in range(plen)] + [0] * (16 - plen)],
+                         jnp.int32)
+    _, after, _ = eng._prefill(
+        params, before, eng._state, tokens, table_row, jnp.int32(plen),
+        jnp.int32(slot), jnp.uint32(0), jnp.uint32(0), jnp.float32(0.0),
+        jnp.int32(0), jnp.float32(1.0), jnp.int32(4), jnp.uint32(2**32 - 1),
+        jnp.int32(2**31 - 1))
+    written = sorted(pages + [trash])
+    flat_before = jax.tree_util.tree_flatten_with_path(before)[0]
+    flat_after = jax.tree.leaves(after)
+    pools = 0
+    for (path, old), new in zip(flat_before, flat_after):
+        old, new = np.asarray(old, np.float32), np.asarray(new, np.float32)
+        if any(getattr(k, "key", None) == "mamba" for k in path):
+            others = [i for i in range(old.shape[1]) if i != slot]
+            np.testing.assert_array_equal(old[:, others], new[:, others])
+            continue
+        pools += 1
+        assert old.shape[1] == rows, (path, old.shape)
+        for layer in range(old.shape[0]):
+            changed = np.flatnonzero(
+                (old[layer] != new[layer]).any(axis=(1, 2)))
+            assert changed.tolist() == written, (path, layer, changed)
+    assert pools > 0
+
+
 def test_decode_kv_pages_counts_live_pages():
     """``Engine.decode_kv_pages`` is Σ ceil(context / page_size) over the
     active slot-iterations: a request with a prompt of L tokens that got N

@@ -172,25 +172,116 @@ def test_moe_grouped_product_compiles(one_chip, tokens):
 
 def test_mla_paged_decode_layer_compiles(one_chip):
     """One latent-attention layer decoding 32 slots over a latent page pool
-    of 8,192 positions each (576 lanes a token), at DeepSeek-V2's widths:
-    it compiles, and its arguments and temporaries fit one v5e."""
+    of 8,192 positions each, at DeepSeek-V2's widths: the engine's pool of
+    the cell's 7 expert layers (576 lanes a token, padded to 640), read at
+    a traced layer.  It compiles, and its arguments and temporaries fit one
+    v5e."""
+    import dataclasses
     from repro.models import blocks as B
+    from repro.models import lm
     cfg = _v2()
     slots, maxp = 32, 8192 // PAGE
     p = _bf16_shapes(jax.eval_shape(lambda k: B.init_mla(cfg, k),
                                     jax.random.PRNGKey(0)), one_chip)
-    lanes = cfg.kv_lora_rank + cfg.rope_head_dim
+    pools = jax.eval_shape(lambda: lm.init_paged_cache(
+        dataclasses.replace(cfg, num_layers=8), slots, slots * maxp, PAGE))
+    pool = pools["dec"][1][0]["mla"]["latent"]
+    assert pool.shape[0] == 7
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
-        ((slots, 1, cfg.d_model), BF16),
-        ((slots * maxp + 1, PAGE, lanes), BF16),
-        ((slots,), I32), ((slots, maxp), I32))]
+        ((slots, 1, cfg.d_model), BF16), (pool.shape, BF16),
+        ((slots,), I32), ((slots, maxp), I32), ((), I32))]
 
-    def layer(p, x, pool, pos, table):
+    def layer(p, x, pool, pos, table, at):
         return B.mla_apply(cfg, p, x, positions=pos[:, None],
                            cache={"latent": pool}, cache_pos=pos,
-                           page_table=table, page_size=PAGE)
+                           page_table=table, page_size=PAGE, layer=at)
 
     compiled = jax.jit(layer).lower(p, *args).compile()
     m = compiled.memory_analysis()
     used = m.argument_size_in_bytes + m.temp_size_in_bytes
     assert used < 15.75 * 2 ** 30, used
+
+
+def _engine_program(cfg, program, sharding, slots=4, max_seq=512):
+    """The serving engine's decode segment or batch-1 prefill (a bucket of
+    64 tokens) for ``cfg``, compiled with the caches and state donated as
+    the engine donates them on a chip, bf16 weights; → (compiled HLO text,
+    the pools' rows, page size)."""
+    import functools
+    from repro.models import lm
+    from repro.serve import engine as E
+
+    ecfg = E.EngineConfig(num_slots=slots, page_size=PAGE, max_seq=max_seq)
+    maxp = ecfg.max_pages_per_slot
+    pages = slots * maxp
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    params = _bf16_shapes(jax.eval_shape(lambda k: lm.init_params(cfg, k),
+                                         jax.random.PRNGKey(0)), sharding)
+    caches = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: lm.init_paged_cache(cfg, slots, pages, PAGE)))
+    state = E.DecodeState(
+        tok=sds((slots,), I32), pos=sds((slots,), I32),
+        gen=sds((slots,), I32), limit=sds((slots,), I32),
+        active=sds((slots,), bool), bad=sds((slots,), bool),
+        uids=sds((slots,), jnp.uint32), temp=sds((slots,), jnp.float32),
+        top_k=sds((slots,), I32), top_p=sds((slots,), jnp.float32))
+    u32, i32, f32 = (sds((), jnp.uint32), sds((), I32),
+                     sds((), jnp.float32))
+    fn = {"decode_segment": E._decode_segment,
+          "prefill": E._prefill_one}[program]
+    if program == "decode_segment":
+        rest = (sds((slots, maxp), I32), u32, sds((), bool), u32, i32)
+    else:
+        rest = (sds((1, 64), I32), sds((1, maxp), I32), i32, i32, u32, u32,
+                f32, i32, f32, i32, u32, i32)
+    jitted = jax.jit(functools.partial(fn, cfg, ecfg),
+                     donate_argnums=E.donation_argnums(fn))
+    text = jitted.lower(params, caches, state, *rest).compile().as_text()
+    return text, pages + 1
+
+
+def _minicpm_4l():
+    import dataclasses
+    from repro.configs import get_config
+    return dataclasses.replace(get_config("minicpm_2b"), num_layers=4)
+
+
+def _v2_3l():
+    """The dense first layer and 2 expert layers: an unrolled group of one
+    and a scanned group of two."""
+    import dataclasses
+    return dataclasses.replace(_v2(), num_layers=3)
+
+
+@pytest.mark.parametrize("program", ["decode_segment", "prefill"])
+@pytest.mark.parametrize("make_cfg,max_seq", [(_minicpm_4l, 512),
+                                              (_v2_3l, 8192)],
+                         ids=["minicpm-2b-4l", "deepseek-v2-3l"])
+def test_engine_programs_write_pools_in_place(one_chip, make_cfg, max_seq,
+                                              program):
+    """The paged K/V (MLA: latent) pools pass through the engine's programs
+    whole: the layer scan and the decode loop carry them, and each layer
+    scatters its new rows in place.  No copy, dynamic-slice or
+    dynamic-update-slice has a pool, or one layer of a pool, for its result
+    (shape [..., P+1, page, lanes]): any of them moves a whole pool through
+    HBM on every step or program call.  4 slots of 512 positions stand for
+    the MHA cells' slots.  The latent pool takes the MLA cell's 8,192
+    positions a slot: its row count decides the TPU's default layout of a
+    pool whose rows do not fill whole 128-lane tiles (rows axis minor, and
+    a copy into and out of each program)."""
+    import re
+    cfg = make_cfg()
+    text, rows = _engine_program(cfg, program, one_chip, max_seq=max_seq)
+    pool = rf"bf16\[(?:\d+,)*{rows},{PAGE},\d+\]\S*"
+    moved = re.findall(
+        rf"(%\S+) = {pool} (?:copy|dynamic-slice|dynamic-update-slice)\(",
+        text)
+    assert not moved, moved
+    # the rows are written into the pools in place, as scatters
+    assert re.search(rf"= {pool} scatter\(", text)
+    if program == "decode_segment" and not cfg.use_mla:
+        assert "tpu_custom_call" in text      # the paged kernel reads them
